@@ -17,6 +17,9 @@ def main() -> None:
                          "error, rows) to PATH")
     args = ap.parse_args()
 
+    from . import common  # noqa: F401  (puts src/ on sys.path)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (copartition, deploy_e2e, device_search, fault_replace,
                    multichip, multilevel, noc_eval, paper_figs, ppo_pipeline,
                    roofline, service, spike_kernel, tpu_placement)

@@ -69,6 +69,12 @@ def _import_jax():
     return jax, jnp
 
 
+def _mm(a, b):
+    """Matmul over traffic volumes at full float32 precision: a TPU's default
+    single bf16 MXU pass would round them (CPU computes f32 exactly anyway)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _jx_float():
     """float64 when x64 is enabled (reference-grade precision; summation
     order can still differ in the last ulp), else float32."""
@@ -534,12 +540,12 @@ class BatchedNoC:
                 comp = jnp.zeros((B, n), vol.dtype).at[
                     jnp.arange(B)[:, None], P].set(comp_nodes[None, :])
                 if t.uniform:
-                    core_tr = lt @ dst_oh.astype(vol.dtype)      # [B, n]
+                    core_tr = _mm(lt, dst_oh.astype(vol.dtype))  # [B, n]
                     per_core_max = (comp + core_tr * inv_bw).max(axis=1)
                     return cc, h.max(axis=1), lt, core_tr, per_core_max
-                core_tr = lt @ dst_oh.astype(vol.dtype)
-                wct = (lt * inv_bw_l[None, :].astype(vol.dtype)) @ \
-                    dst_oh.astype(vol.dtype)
+                core_tr = _mm(lt, dst_oh.astype(vol.dtype))
+                wct = _mm(lt * inv_bw_l[None, :].astype(vol.dtype),
+                          dst_oh.astype(vol.dtype))
                 per_core_max = (comp + wct).max(axis=1)
                 plat = route_lat_flat[s * n + d].max(axis=1)
                 return cc, h.max(axis=1), lt, core_tr, per_core_max, plat
@@ -673,7 +679,7 @@ class BatchedNoC:
         def core_sum(lt, dtype):
             """[B, n_links] -> [B, n] sum of link values into their dst core."""
             if resolved == "pallas":
-                return lt @ dst_oh.astype(dtype)
+                return _mm(lt, dst_oh.astype(dtype))
             return jax.vmap(lambda x: jnp.zeros(n, dtype).at[link_dst]
                             .add(x))(lt)
 
@@ -694,7 +700,7 @@ class BatchedNoC:
                 if "max_link" in w:
                     total = total + w["max_link"] * lt.max(axis=1)
                 if "interchip" in w and ic is not None:
-                    total = total + w["interchip"] * (lt @ ic.astype(vol.dtype))
+                    total = total + w["interchip"] * _mm(lt, ic.astype(vol.dtype))
                 if need_latency:
                     B = P.shape[0]
                     comp = jnp.zeros((B, n), vol.dtype).at[
@@ -711,7 +717,7 @@ class BatchedNoC:
                         total = total + w["latency"] * latency
                     if "energy" in w:
                         dyn = (e_byte_hop * cc if eb is None
-                               else lt @ eb.astype(vol.dtype))
+                               else _mm(lt, eb.astype(vol.dtype)))
                         total = total + w["energy"] * (
                             dyn + static_w * latency)
             return total

@@ -19,7 +19,8 @@ single ``jax.jit``-ed ``lax.scan`` dispatch:
   plain jax hop-matrix gathers (CPU default) or by the tiled Pallas one-hot
   matmul kernel :func:`repro.kernels.delta_cost.delta_cost_pallas`
   (``use_pallas=True``; interpret mode on CPU, Mosaic on TPU — the default on
-  TPU hosts, where dynamic gathers lower poorly). Float32 drift of the
+  TPU hosts, where dynamic gathers lower poorly, for fabrics whose hop matrix
+  fits the kernel's VMEM block: :data:`PALLAS_DELTA_MAX_CORES`). Float32 drift of the
   accumulated cost is bounded by an exact full re-evaluation every
   ``refresh_every`` steps (``lax.cond``, still on device).
 * :func:`genetic_device` — the OX1-crossover evolutionary search as a scanned
@@ -57,6 +58,14 @@ import jax
 import jax.numpy as jnp
 
 from ...kernels.delta_cost import delta_cost_pallas
+
+
+#: Largest fabric (cores) whose delta kernel runs on TPU by default: the
+#: kernel holds the whole hop matrix, padded to 128-multiples, as one VMEM
+#: block, double-buffered. At 1024 cores that is 2 x 4 MiB and compiles for
+#: v5e; at 2048 cores, 2 x 16 MiB, it runs out of VMEM. Larger fabrics take
+#: the plain-jax gather path.
+PALLAS_DELTA_MAX_CORES = 1024
 
 
 def _on_tpu() -> bool:
@@ -198,6 +207,43 @@ def _sa_chains(slots0, keys0, t0_vec, cooling, inc_other, inc_vol, inc_src,
     return best_slots, best_cost, traj
 
 
+def _sa_inputs(graph, noc, iters, t0, t_end_frac, seed, init, restarts,
+               t0_spread, use_pallas, refresh_every):
+    """``(args, static)`` such that ``_sa_chains(*args, **static)`` is the
+    device-SA dispatch of :func:`simulated_annealing_device` — also what a
+    caller lowers to inspect or compile that program."""
+    rng = np.random.default_rng(seed)
+    pool_arr = _pool_array(noc)
+    n = graph.n
+    base = np.asarray(init if init is not None else zigzag(n, noc), dtype=int)
+    validate_placements(noc, base, n)
+    free = np.setdiff1d(pool_arr, base)
+    slots0 = np.empty((restarts, pool_arr.size), dtype=np.int32)
+    slots0[0] = np.concatenate([base, free])
+    pool = core_pool(noc)
+    for r in range(1, restarts):
+        slots0[r] = rng.permutation(pool)
+
+    bn = batched_noc(noc)
+    inc = build_incident_tables(graph)
+    e_src, e_dst, e_vol, _ = bn.edge_arrays(graph)
+    if use_pallas is None:
+        use_pallas = _on_tpu() and bn.tables.n_cores <= PALLAS_DELTA_MAX_CORES
+    spread = (t0_spread ** (np.arange(restarts) / max(restarts - 1, 1))
+              if restarts > 1 else np.ones(1))
+    args = (jnp.asarray(slots0), _chain_keys(seed, restarts),
+            jnp.asarray(t0 * spread, jnp.float32),
+            jnp.float32(t_end_frac ** (1.0 / max(iters, 1))),
+            jnp.asarray(inc.other), jnp.asarray(inc.vol, jnp.float32),
+            jnp.asarray(inc.is_src),
+            jnp.asarray(bn.tables.hops, jnp.float32),
+            jnp.asarray(e_src, jnp.int32), jnp.asarray(e_dst, jnp.int32),
+            jnp.asarray(e_vol, jnp.float32))
+    static = dict(iters=iters, n=n, refresh_every=refresh_every,
+                  use_pallas=bool(use_pallas), interpret=not _on_tpu())
+    return args, static
+
+
 def simulated_annealing_device(graph, noc, iters: int = 5000,
                                t0: float = 0.05, t_end_frac: float = 1e-3,
                                seed: int = 0, init=None, restarts: int = 1,
@@ -215,45 +261,20 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
     ``t0_spread`` stretches the chains' initial temperatures geometrically
     from ``t0`` to ``t0 * t0_spread`` (1.0 = all equal), annealing restarts at
     different aggressiveness for free. ``use_pallas=None`` picks the Pallas
-    delta kernel on TPU and plain jax gathers on CPU (where interpret-mode
-    Pallas is correct but slow); ``recorder`` replays one ``sa.iter`` event
-    per step of the winning chain after the dispatch (identical schema to the
-    host SA) plus one ``sa.device`` summary — results are bit-identical with
+    delta kernel on TPU for fabrics up to :data:`PALLAS_DELTA_MAX_CORES`
+    cores and plain jax gathers otherwise (on CPU, interpret-mode Pallas is
+    correct but slow); the choice is recorded in ``sa.device``.
+    ``recorder`` replays one ``sa.iter`` event per step of the winning chain
+    after the dispatch (identical schema to the host SA) plus one
+    ``sa.device`` summary — results are bit-identical with
     or without it.
     """
     if restarts < 1:
         raise ValueError(f"restarts must be >= 1, got {restarts}")
     _check_objective(objective)
-    rng = np.random.default_rng(seed)
-    pool_arr = _pool_array(noc)
-    n = graph.n
-    base = np.asarray(init if init is not None else zigzag(n, noc), dtype=int)
-    validate_placements(noc, base, n)
-    free = np.setdiff1d(pool_arr, base)
-    slots0 = np.empty((restarts, pool_arr.size), dtype=np.int32)
-    slots0[0] = np.concatenate([base, free])
-    pool = core_pool(noc)
-    for r in range(1, restarts):
-        slots0[r] = rng.permutation(pool)
-
-    bn = batched_noc(noc)
-    inc = build_incident_tables(graph)
-    e_src, e_dst, e_vol, _ = bn.edge_arrays(graph)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    spread = (t0_spread ** (np.arange(restarts) / max(restarts - 1, 1))
-              if restarts > 1 else np.ones(1))
-    best_slots, best_cost, traj = _sa_chains(
-        jnp.asarray(slots0), _chain_keys(seed, restarts),
-        jnp.asarray(t0 * spread, jnp.float32),
-        jnp.float32(t_end_frac ** (1.0 / max(iters, 1))),
-        jnp.asarray(inc.other), jnp.asarray(inc.vol, jnp.float32),
-        jnp.asarray(inc.is_src),
-        jnp.asarray(bn.tables.hops, jnp.float32),
-        jnp.asarray(e_src, jnp.int32), jnp.asarray(e_dst, jnp.int32),
-        jnp.asarray(e_vol, jnp.float32),
-        iters=iters, n=n, refresh_every=refresh_every,
-        use_pallas=bool(use_pallas), interpret=not _on_tpu())
+    args, static = _sa_inputs(graph, noc, iters, t0, t_end_frac, seed, init,
+                              restarts, t0_spread, use_pallas, refresh_every)
+    best_slots, best_cost, traj = _sa_chains(*args, **static)
     best_cost = np.asarray(best_cost)
     win = int(np.argmin(best_cost))
     if recorder is not None:
@@ -271,9 +292,9 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
         recorder.event("sa.device", restarts=restarts, iters=iters,
                        best_chain=win, best_cost=float(best_cost[win]),
                        chain_best_mean=float(best_cost.mean()),
-                       use_pallas=bool(use_pallas),
+                       use_pallas=static["use_pallas"],
                        refresh_every=refresh_every)
-    return np.asarray(best_slots)[win, :n].astype(np.int64)
+    return np.asarray(best_slots)[win, :graph.n].astype(np.int64)
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "restarts"))

@@ -553,6 +553,9 @@ def request_main(argv=None) -> int:
 
 def main(argv=None) -> int:
     import sys
+
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] == "report":

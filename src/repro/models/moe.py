@@ -150,21 +150,9 @@ def _moe_ep(p, x, cfg: MoEConfig, mesh):
     FFN, inverse all-to-all, local combine. Shared experts run outside in
     plain GSPMD.
     """
-    from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
-
-    import inspect
-    _sm_params = inspect.signature(_sm).parameters
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw and "check_vma" not in _sm_params:
-            kw["check_rep"] = kw.pop("check_vma")   # pre-0.6 jax spelling
-        return _sm(f, **kw)
-
     import math
+
+    from jax.sharding import PartitionSpec as P
     b, s, d = x.shape
     n_ep = mesh.shape["model"]
     e, e_loc = cfg.n_experts, cfg.n_experts // n_ep
@@ -202,7 +190,7 @@ def _moe_ep(p, x, cfg: MoEConfig, mesh):
         out = _combine(y, meta, t, x_loc.dtype)
         return out.reshape(b_loc, s_loc, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), x_spec),

@@ -21,9 +21,6 @@ def _run(code, devices=8):
 
 @pytest.mark.slow
 def test_scan_flops_multiplied_by_trip_count():
-    import jax
-    if not hasattr(jax.sharding, "AxisType"):
-        pytest.skip("installed jax predates jax.sharding.AxisType")
     out = _run("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
