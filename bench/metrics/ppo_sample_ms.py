@@ -1,0 +1,8 @@
+"""Search: median, over searched answers, of the request's time in the
+``ppo.sample`` phase: actor forward and action sampling, up to the
+actions on the host, summed over the PPO iterations, in ms."""
+from bench.phases import search_phase_ms
+
+
+def read(run):
+    return search_phase_ms(run, "ppo.sample")

@@ -50,6 +50,7 @@ import functools
 import numpy as np
 
 from ...deploy.objective import as_objective
+from ...obs import maybe_span
 from ..noc_batch import (batched_noc, build_incident_tables,
                          validate_placements)
 from .baselines import core_pool, sigmate, zigzag
@@ -59,6 +60,9 @@ import jax.numpy as jnp
 
 from ...kernels.delta_cost import delta_cost_pallas
 
+
+#: the phases of one device SA search, each a span
+SA_PHASES = ("sa.prepare", "sa.run", "sa.select")
 
 #: Largest fabric (cores) whose delta kernel runs on TPU by default: the
 #: kernel holds the whole hop matrix, padded to 128-multiples, as one VMEM
@@ -250,7 +254,8 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
                                t0_spread: float = 1.0,
                                objective="comm_cost", use_pallas=None,
                                refresh_every: int = 256,
-                               recorder=None) -> np.ndarray:
+                               recorder=None,
+                               phases_s: dict | None = None) -> np.ndarray:
     """Device-resident pairwise-swap SA, ``restarts`` parallel chains.
 
     One compiled dispatch advances all chains ``iters`` steps with O(degree)
@@ -268,15 +273,31 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
     after the dispatch (identical schema to the host SA) plus one
     ``sa.device`` summary — results are bit-identical with
     or without it.
+
+    The search runs as three spans (:data:`SA_PHASES`), each ending at a
+    host sync it needs anyway: ``sa.prepare`` (:func:`_sa_inputs`: start
+    placements, incident tables, uploads), ``sa.run`` (the ``_sa_chains``
+    dispatch, up to the chains' best costs on the host) and ``sa.select``
+    (the winning chain's placement to the host). A ``phases_s`` dict, when
+    given, receives their durations.
     """
     if restarts < 1:
         raise ValueError(f"restarts must be >= 1, got {restarts}")
     _check_objective(objective)
-    args, static = _sa_inputs(graph, noc, iters, t0, t_end_frac, seed, init,
-                              restarts, t0_spread, use_pallas, refresh_every)
-    best_slots, best_cost, traj = _sa_chains(*args, **static)
-    best_cost = np.asarray(best_cost)
+    with maybe_span(recorder, "sa.prepare") as sp_prepare:
+        args, static = _sa_inputs(graph, noc, iters, t0, t_end_frac, seed,
+                                  init, restarts, t0_spread, use_pallas,
+                                  refresh_every)
+    with maybe_span(recorder, "sa.run") as sp_run:
+        best_slots, best_cost, traj = _sa_chains(*args, **static)
+        best_cost = np.asarray(best_cost)
     win = int(np.argmin(best_cost))
+    with maybe_span(recorder, "sa.select") as sp_select:
+        placement = np.asarray(best_slots)[win, :graph.n].astype(np.int64)
+    if phases_s is not None:
+        phases_s.update({"sa.prepare": sp_prepare.duration_s,
+                         "sa.run": sp_run.duration_s,
+                         "sa.select": sp_select.duration_s})
     if recorder is not None:
         cost_tr, best_tr, t_tr, acc_tr, prop_tr = (
             np.asarray(y) for y in traj)
@@ -294,7 +315,7 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
                        chain_best_mean=float(best_cost.mean()),
                        use_pallas=static["use_pallas"],
                        refresh_every=refresh_every)
-    return np.asarray(best_slots)[win, :graph.n].astype(np.int64)
+    return placement
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "restarts"))

@@ -56,6 +56,9 @@ class PlacementResult:
     history: list | None = None
     objective: str = "comm_cost"
     objective_cost: float = float("nan")
+    #: seconds per search phase (``ppo.*``, ``sa.*``); empty for methods
+    #: that time none
+    phases_s: dict = dataclasses.field(default_factory=dict)
 
     def summary(self) -> dict:
         return {
@@ -82,7 +85,8 @@ METHOD_ALIASES = {"sa": "simulated_annealing", "ga": "genetic",
 
 # arguments optimize_placement supplies itself — never forwardable via **kw
 _DRIVER_PARAMS = frozenset({"graph", "noc", "seed", "backend", "objective",
-                            "recorder", "budget", "generations", "iters"})
+                            "recorder", "budget", "generations", "iters",
+                            "phases_s"})
 
 
 def _fn_kwargs(fn) -> frozenset:
@@ -185,6 +189,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
     (``zigzag``/``sigmate``/``greedy``) stay chip-oblivious baselines.
     """
     history = None
+    phases: dict = {}
     method = METHOD_ALIASES.get(method, method)
     validate_method_kw(method, kw, backend=backend)
     bk = backend or "batch"
@@ -226,7 +231,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
             if bk == "device":
                 placement = device_search.simulated_annealing_device(
                     graph, noc, iters=iters, seed=seed, objective=ob,
-                    recorder=recorder, **kw)
+                    recorder=recorder, phases_s=phases, **kw)
             else:
                 placement = baselines.simulated_annealing(
                     graph, noc, iters=iters, seed=seed, backend=bk,
@@ -290,6 +295,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
                 cfg = _override_cfg(cfg, backend, objective)
             st = run_ppo(graph, noc, cfg, recorder=recorder)
             placement, history = st.best_placement, st.history
+            phases = st.phases_s
             ob = cfg.objective
         else:
             raise ValueError(f"unknown method {method!r}; "
@@ -313,7 +319,8 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
         comm_cost=m.comm_cost, mean_hops=m.mean_hops, latency=m.latency,
         throughput=m.throughput, max_link=m.max_link,
         wall_time_s=sp.duration_s, history=history,
-        objective=obj.name, objective_cost=obj.from_metrics(m, noc, placement))
+        objective=obj.name, objective_cost=obj.from_metrics(m, noc, placement),
+        phases_s=phases)
 
 
 def _reject_cfg_extras(method, cfg, kw):

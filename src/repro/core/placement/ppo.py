@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...obs import maybe_span
 from ...train.optim import AdamWConfig, adamw_init, adamw_update
 from ..noc_batch import make_scorer
 from . import actor_critic as ac
@@ -125,6 +126,11 @@ def _ppo_update_scan(actor, critic, opt_a, opt_c, lap, feats, acts, logp_old,
     return actor, critic, opt_a, opt_c, las[-1], lcs[-1]
 
 
+#: the phases of one PPO iteration, each a span; ``PPOState.phases_s`` sums
+#: each over the iterations
+PPO_PHASES = ("ppo.sample", "ppo.discretize", "ppo.score", "ppo.update")
+
+
 @dataclasses.dataclass
 class PPOState:
     actor: dict
@@ -134,6 +140,7 @@ class PPOState:
     history: list
     best_cost: float
     best_placement: np.ndarray
+    phases_s: dict = dataclasses.field(default_factory=dict)
 
 
 def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
@@ -143,7 +150,14 @@ def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
     ``recorder`` (a :class:`repro.obs.Recorder`) emits one ``ppo.iter`` event
     per iteration — mean/min rollout cost, best-so-far, and the PPO policy /
     value losses — plus scoring dispatch counters; the training trajectory is
-    bit-identical with or without it (no RNG or float path touched)."""
+    bit-identical with or without it (no RNG or float path touched).
+
+    Each iteration runs as four spans (:data:`PPO_PHASES`), each ending at a
+    host sync the loop needs anyway: ``ppo.sample`` (actor forward and
+    sampling, up to the actions on the host), ``ppo.discretize``,
+    ``ppo.score`` (host scoring) and ``ppo.update`` (rewards, the fused
+    update dispatch, up to the losses on the host). ``phases_s`` of the
+    returned state sums each over the iterations."""
     key = jax.random.PRNGKey(cfg.seed)
     lap = jnp.asarray(graph.laplacian(), jnp.float32)
     feats = jnp.asarray(graph.node_features(), jnp.float32)
@@ -171,40 +185,53 @@ def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
         resolver = make_jax_resolver(noc.rows, noc.cols, priority)
     best_cost, best_placement = np.inf, None
     history = []
+    phases = dict.fromkeys(PPO_PHASES, 0.0)
     for it in range(cfg.iterations):
-        key, k_s = jax.random.split(key)
-        mu, log_std = ac.actor_apply(actor, lap, feats)
-        acts, logp_old = ac.sample_actions(k_s, mu, log_std, cfg.batch_size)
-        acts_np = np.asarray(acts, np.float64)
-        if resolver is not None:
-            cells = continuous_to_grid_batch(acts_np, noc.rows, noc.cols,
-                                             cfg.action_clip)
-            placements = np.asarray(resolver(cells), np.int64)
-        else:
-            placements = actions_to_placement_batch(
-                acts_np, noc.rows, noc.cols, cfg.action_clip, priority)
-        costs = score(placements)        # whole rollout batch in one call
-        b_min = int(costs.argmin())
-        if costs[b_min] < best_cost:
-            best_cost, best_placement = costs[b_min], placements[b_min]
-        rewards = np.clip(cfg.reward_clip * (baseline_cost - costs) / baseline_cost,
-                          -cfg.reward_clip, cfg.reward_clip)
-        rewards = jnp.asarray(rewards, jnp.float32)
-        # acts/logp_old/rewards stay device-resident; all ppo_epochs run in
-        # one fused dispatch (lax.scan) instead of ppo_epochs round-trips.
-        actor, critic, opt_a, opt_c, la, lc = _ppo_update_scan(
-            actor, critic, opt_a, opt_c, lap, feats, acts, logp_old, rewards,
-            cfg.ppo_epochs, cfg.clip, cfg.entropy_coef, cfg.freeze_gcn,
-            adam, adam)
+        with maybe_span(recorder, "ppo.sample") as sp:
+            key, k_s = jax.random.split(key)
+            mu, log_std = ac.actor_apply(actor, lap, feats)
+            acts, logp_old = ac.sample_actions(k_s, mu, log_std,
+                                               cfg.batch_size)
+            acts_np = np.asarray(acts, np.float64)
+        phases["ppo.sample"] += sp.duration_s
+        with maybe_span(recorder, "ppo.discretize") as sp:
+            if resolver is not None:
+                cells = continuous_to_grid_batch(acts_np, noc.rows, noc.cols,
+                                                 cfg.action_clip)
+                placements = np.asarray(resolver(cells), np.int64)
+            else:
+                placements = actions_to_placement_batch(
+                    acts_np, noc.rows, noc.cols, cfg.action_clip, priority)
+        phases["ppo.discretize"] += sp.duration_s
+        with maybe_span(recorder, "ppo.score") as sp:
+            costs = score(placements)    # whole rollout batch in one call
+            b_min = int(costs.argmin())
+            if costs[b_min] < best_cost:
+                best_cost, best_placement = costs[b_min], placements[b_min]
+        phases["ppo.score"] += sp.duration_s
+        with maybe_span(recorder, "ppo.update") as sp:
+            rewards = np.clip(
+                cfg.reward_clip * (baseline_cost - costs) / baseline_cost,
+                -cfg.reward_clip, cfg.reward_clip)
+            rewards = jnp.asarray(rewards, jnp.float32)
+            # acts/logp_old/rewards stay device-resident; all ppo_epochs run
+            # in one fused dispatch (lax.scan) instead of ppo_epochs
+            # round-trips.
+            actor, critic, opt_a, opt_c, la, lc = _ppo_update_scan(
+                actor, critic, opt_a, opt_c, lap, feats, acts, logp_old,
+                rewards, cfg.ppo_epochs, cfg.clip, cfg.entropy_coef,
+                cfg.freeze_gcn, adam, adam)
+            actor_loss, critic_loss = float(la), float(lc)
+        phases["ppo.update"] += sp.duration_s
         history.append({
             "iter": it,
             "mean_cost": float(costs.mean()),
             "min_cost": float(costs[b_min]),
             "best_cost": float(best_cost),
-            "actor_loss": float(la),
-            "critic_loss": float(lc),
+            "actor_loss": actor_loss,
+            "critic_loss": critic_loss,
         })
         if recorder is not None:
             recorder.event("ppo.iter", **history[-1])
     return PPOState(actor, critic, opt_a, opt_c, history, float(best_cost),
-                    best_placement)
+                    best_placement, phases)

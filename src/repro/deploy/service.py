@@ -30,7 +30,14 @@ and benchmarks); :func:`make_server` wraps it in a stdlib
 connections through a :class:`repro.launch.serve.MicroBatchQueue` — the same
 continuous-batching idiom as the token server. Per-request latencies land in
 the service :class:`repro.obs.Recorder` as ``service.latency_s`` histograms
-(p50/p99 via ``/stats``), and hit/miss/warm/fused counts as counters.
+(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts as
+counters; the recorder stores no spans. The served path's spans
+(``http.deploy``, ``queue.window``, ``service.batch``, ``service.deploy``,
+the engine's ``deploy.*`` stages and the searches' phases) are profiler
+annotations only. Each answer carries its own ``latency_s``, ``queue_s``,
+``report["search_phases_s"]`` and ``report["compiles"]``; its
+``report["stage_times_s"]`` is the cached plan's (a hit repeats the stage
+times of the search that made the plan).
 
 HTTP surface: ``POST /deploy`` (one request JSON -> DeployResponse JSON,
 micro-batched), ``POST /deploy_batch`` (``{"requests": [...]}`` -> fused as
@@ -42,12 +49,14 @@ import dataclasses
 import json
 import threading
 import time
+from contextlib import contextmanager
 
+import jax
 import numpy as np
 
 from ..core.partition import partition_model
 from ..launch.serve import MicroBatchQueue
-from ..obs import Recorder
+from ..obs import Recorder, maybe_span
 from .engine import _profiles, execute_request
 from .plancache import PlanCache, _obj_blob
 from .request import DeployRequest
@@ -66,6 +75,25 @@ _DEFAULT_BUDGET = {"random_search": 2000, "simulated_annealing": 5000,
 #: methods the fused batch path replays bit-exactly (host backend only)
 _FUSE_METHODS = frozenset({"simulated_annealing", "random_search"})
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = [0]             # backend compiles in this process since listening
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        _compiles[0] += 1
+
+
+def _listen_for_compiles() -> None:
+    """Register, once per process, the jax listener that counts compiles."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _listening = True
+
 
 @dataclasses.dataclass
 class DeployResponse:
@@ -75,8 +103,16 @@ class DeployResponse:
     warm-started from ``warm_from``'s placement) or ``"miss"`` (cold search;
     ``fused=True`` when it ran as a row of a batched dispatch). ``latency_s``
     is the service-side wall time of this request (for fused rows: of the
-    whole batch). ``request`` + ``placement`` are enough to re-materialize a
-    live plan via :func:`repro.deploy.engine.instantiate_plan`.
+    whole batch). ``queue_s`` is the time the request waited in the HTTP
+    micro-batch queue (0.0 for in-process calls). ``report["compiles"]``
+    counts the jax backend compiles in the process while the request ran,
+    programs loaded from the persistent compile cache included (for fused
+    rows: while the batch ran); ``report["search_phases_s"]`` holds the
+    phase times of this request's own search, summed over a warm start's
+    attempts (empty for a hit, a fused row, or a method that times none).
+    ``request`` + ``placement`` are enough to
+    re-materialize a live plan via
+    :func:`repro.deploy.engine.instantiate_plan`.
     """
     status: str
     cache_key: str
@@ -89,6 +125,7 @@ class DeployResponse:
     warm_from: str | None = None
     attempts: int = 1
     fused: bool = False
+    queue_s: float = 0.0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -104,9 +141,11 @@ class PlacementService:
 
     ``cache`` defaults to a fresh in-memory :class:`PlanCache` (load one from
     disk for restart persistence). ``recorder`` collects the service metrics
-    (a private one is created when omitted); the deployment engine itself
-    runs un-instrumented — results are bit-identical either way and a
-    long-lived server must not accumulate per-iteration search events.
+    (a private one is created when omitted): counters and latency
+    histograms, never spans or events, so a long-lived server's recorder
+    stays bounded. The deployment engine runs with no recorder; its spans
+    are profiler annotations and its stage and search-phase times come back
+    in each answer's report.
 
     Warm-start control mirrors ``run_scenario``: the first attempt runs at
     ``warm_budget_frac`` of the full budget seeded with the donor placement;
@@ -131,11 +170,12 @@ class PlacementService:
         self._topologies: dict = {}     # topology key tuple -> live Topology
         self._models: dict = {}         # model spec tuple -> live model
         self._lock = threading.RLock()
+        _listen_for_compiles()
 
     # ---- public API --------------------------------------------------------
     def submit(self, request: DeployRequest) -> DeployResponse:
         """Answer one request: cache hit, warm start, or cold search."""
-        with self._lock:
+        with self._serving():
             return self._submit(request)
 
     def submit_batch(self, requests) -> list:
@@ -146,7 +186,7 @@ class PlacementService:
         (Serially submitting the same sequence can differ legitimately:
         earlier requests' entries become warm-start donors for later ones.)
         """
-        with self._lock:
+        with self._serving(), maybe_span(None, "service.batch"):
             requests = list(requests)
             responses: list = [None] * len(requests)
             groups: dict = {}
@@ -181,6 +221,16 @@ class PlacementService:
                     responses[idx] = self._submit(requests[idx])
             return responses
 
+    @contextmanager
+    def _serving(self):
+        """Hold the service lock; count the compiles made meanwhile."""
+        with self._lock:
+            c0 = _compiles[0]
+            try:
+                yield
+            finally:
+                self.recorder.count("service.compiles", _compiles[0] - c0)
+
     def stats(self) -> dict:
         """Cache size + service counters + latency histogram summaries."""
         with self._lock:
@@ -190,38 +240,43 @@ class PlacementService:
 
     # ---- request handling --------------------------------------------------
     def _submit(self, request: DeployRequest) -> DeployResponse:
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), _compiles[0]
         rec = self.recorder
         ck = request.cache_key()
         rec.count("service.requests")
         entry = self.cache.get(ck)
         if entry is not None:
             rec.count("service.hits")
-            return self._finish(entry, "hit", t0)
+            return self._finish(entry, "hit", t0, c0)
         donor = (self.cache.find_warm(request)
                  if self._warm_startable(request) else None)
         if donor is not None:
             try:
-                with rec.span("service.deploy", status="warm", key=ck[:12]):
-                    plan, attempts = self._deploy_warm(request, donor)
+                with maybe_span(None, "service.deploy"):
+                    plan, attempts, phases = self._deploy_warm(request,
+                                                               donor)
             except ValueError:
                 donor = None            # incompatible donor: run cold
             else:
                 rec.count("service.warm_starts")
                 entry = self.cache.put(request, plan)
-                return self._finish(entry, "warm", t0,
+                return self._finish(entry, "warm", t0, c0, phases,
                                     warm_from=donor["cache_key"],
                                     attempts=attempts)
-        with rec.span("service.deploy", status="miss", key=ck[:12]):
+        with maybe_span(None, "service.deploy"):
             model, noc = self._materialize(request)
             plan = execute_request(request, model=model, noc=noc)
         rec.count("service.misses")
         entry = self.cache.put(request, plan)
-        return self._finish(entry, "miss", t0)
+        return self._finish(entry, "miss", t0, c0, plan.placement.phases_s)
 
-    def _finish(self, entry: dict, status: str, t0: float,
-                warm_from: str | None = None, attempts: int = 1,
-                fused: bool = False) -> DeployResponse:
+    def _finish(self, entry: dict, status: str, t0: float, c0: int,
+                phases: dict | None = None, warm_from: str | None = None,
+                attempts: int = 1, fused: bool = False) -> DeployResponse:
+        """The answer to one request. Its report is the cached plan's, plus
+        what this request alone did: ``compiles`` since ``c0`` and
+        ``search_phases_s``, the phase times of its own search (``phases``;
+        empty for a hit)."""
         dt = time.perf_counter() - t0
         self.recorder.observe("service.latency_s", dt)
         self.recorder.observe(f"service.latency_s.{status}", dt)
@@ -230,7 +285,9 @@ class PlacementService:
             request=dict(entry["request"]),
             placement=list(entry["placement"]),
             objective_cost=float(entry["objective_cost"]),
-            comm_cost=float(entry["comm_cost"]), report=entry["report"],
+            comm_cost=float(entry["comm_cost"]),
+            report={**entry["report"], "compiles": _compiles[0] - c0,
+                    "search_phases_s": dict(phases or {})},
             latency_s=dt, warm_from=warm_from, attempts=attempts, fused=fused)
 
     def _materialize(self, request: DeployRequest):
@@ -264,6 +321,8 @@ class PlacementService:
         return "budget", _DEFAULT_BUDGET[request.method]
 
     def _deploy_warm(self, request: DeployRequest, donor: dict):
+        """Warm-started search: ``(best plan, attempts, phase times summed
+        over the attempts)``."""
         model, noc = self._materialize(request)
         init = np.asarray(donor["placement"], dtype=int)
         kind, full = self._full_budget(request)
@@ -271,11 +330,13 @@ class PlacementService:
                     == _obj_blob(request.objective))
         target = (1.0 + self.warm_threshold) * float(donor["objective_cost"])
         b = max(1, int(round(self.warm_budget_frac * full)))
-        attempts, best = 0, None
+        attempts, best, phases = 0, None, {}
         while True:
             attempts += 1
             plan = execute_request(request, model=model, noc=noc,
                                    init=init, **{kind: b})
+            for k, v in plan.placement.phases_s.items():
+                phases[k] = phases.get(k, 0.0) + v
             if best is None or (plan.placement.objective_cost
                                 < best.placement.objective_cost):
                 best = plan
@@ -284,7 +345,7 @@ class PlacementService:
             if attempts > self.max_retries or b >= full:
                 break
             b = min(full, max(b + 1, int(round(b * self.escalation))))
-        return best, attempts
+        return best, attempts, phases
 
     # ---- fused batches -----------------------------------------------------
     def _fuse_key(self, request: DeployRequest):
@@ -302,13 +363,12 @@ class PlacementService:
                 json.dumps(request.method_kw, sort_keys=True, default=str))
 
     def _submit_fused(self, requests) -> list:
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), _compiles[0]
         rec = self.recorder
         req0 = requests[0]
         model, noc = self._materialize(req0)
         seeds = [r.seed for r in requests]
-        with rec.span("service.fused_search", rows=len(requests),
-                      method=req0.method):
+        with maybe_span(None, "service.fused_search"):
             placements = _fused_cold_search(req0, model, noc, seeds)
         rec.count("service.fused_batches")
         rec.count("service.fused_rows", len(requests))
@@ -319,7 +379,7 @@ class PlacementService:
             plan = execute_request(req, model=model, noc=noc,
                                    _fixed_placement=pl)
             entry = self.cache.put(req, plan)
-            out.append(self._finish(entry, "miss", t0, fused=True))
+            out.append(self._finish(entry, "miss", t0, c0, fused=True))
         return out
 
 
@@ -492,6 +552,10 @@ def make_server(service: PlacementService, host: str = "127.0.0.1",
             return self._json(404, {"error": f"unknown path {self.path!r}"})
 
         def do_POST(self):
+            with maybe_span(None, "http.deploy"):
+                return self._post()
+
+        def _post(self):
             length = int(self.headers.get("Content-Length") or 0)
             try:
                 body = json.loads(self.rfile.read(length) or b"{}")
@@ -500,7 +564,9 @@ def make_server(service: PlacementService, host: str = "127.0.0.1",
             try:
                 if self.path == "/deploy":
                     req = DeployRequest.from_json(body)
-                    return self._json(200, queue.submit(req).to_dict())
+                    resp, wait_s = queue.submit_timed(req)
+                    resp.queue_s = wait_s
+                    return self._json(200, resp.to_dict())
                 if self.path == "/deploy_batch":
                     reqs = [DeployRequest.from_json(d)
                             for d in body["requests"]]

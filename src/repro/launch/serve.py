@@ -11,8 +11,8 @@ once, then decoded step-locked. Greedy or temperature sampling.
 a thread-safe submit/drain queue that coalesces requests arriving within a
 window into one batch for a caller-supplied batch processor. The token
 server here and the placement service (:mod:`repro.deploy.service`) share it,
-so it stays dependency-free (stdlib threading only; jax imports below are
-deferred into the functions that need them).
+so it stays dependency-free (stdlib threading and :mod:`repro.obs` only; jax
+imports below are deferred into the functions that need them).
 """
 from __future__ import annotations
 
@@ -21,6 +21,8 @@ import threading
 import time
 
 import numpy as np
+
+from ..obs import maybe_span
 
 
 class MicroBatchQueue:
@@ -31,7 +33,9 @@ class MicroBatchQueue:
     :meth:`submit` blocks the calling thread until its item's result (or the
     batch's exception) is ready — the continuous-batching idiom: requests
     arriving within ``window_s`` of each other (up to ``max_batch``) share
-    one processor dispatch.
+    one processor dispatch. :meth:`submit_timed` also returns how long the
+    item waited, from its submission to the hand-off of its batch to
+    ``process_batch``; the batching window runs in a ``queue.window`` span.
     """
 
     _CLOSE = object()
@@ -53,9 +57,15 @@ class MicroBatchQueue:
     def submit(self, item, timeout: float | None = None):
         """Enqueue ``item``; block until its result is ready and return it
         (re-raising the batch's exception if processing failed)."""
+        return self.submit_timed(item, timeout)[0]
+
+    def submit_timed(self, item, timeout: float | None = None):
+        """:meth:`submit`, returning ``(result, wait_s)``: ``wait_s`` is the
+        time from this call to the hand-off of the item's batch to
+        ``process_batch``."""
         if self._closed:
             raise RuntimeError("queue is closed")
-        done, slot = threading.Event(), {}
+        done, slot = threading.Event(), {"t_submit": time.perf_counter()}
         with self._lock:
             self._pending.append((item, done, slot))
         self._wake.set()
@@ -63,7 +73,7 @@ class MicroBatchQueue:
             raise TimeoutError(f"no result within {timeout}s")
         if "error" in slot:
             raise slot["error"]
-        return slot["result"]
+        return slot["result"], slot["wait_s"]
 
     def close(self, timeout: float = 5.0) -> None:
         """Stop the worker after the current batch; pending items still run."""
@@ -82,12 +92,13 @@ class MicroBatchQueue:
                     continue
             # batching window: let near-simultaneous submissions pile up
             if self.window_s > 0:
-                deadline = time.perf_counter() + self.window_s
-                while time.perf_counter() < deadline:
-                    with self._lock:
-                        if len(self._pending) >= self.max_batch:
-                            break
-                    time.sleep(min(0.001, self.window_s))
+                with maybe_span(None, "queue.window"):
+                    deadline = time.perf_counter() + self.window_s
+                    while time.perf_counter() < deadline:
+                        with self._lock:
+                            if len(self._pending) >= self.max_batch:
+                                break
+                        time.sleep(min(0.001, self.window_s))
             with self._lock:
                 batch = self._pending[:self.max_batch]
                 del self._pending[:self.max_batch]
@@ -96,6 +107,9 @@ class MicroBatchQueue:
                     if self._closed:
                         self._wake.set()   # drain remaining then exit
             items = [it for it, _, _ in batch]
+            t_handoff = time.perf_counter()
+            for _, _, slot in batch:
+                slot["wait_s"] = t_handoff - slot["t_submit"]
             try:
                 results = self._process(items)
                 if len(results) != len(items):

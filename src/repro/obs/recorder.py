@@ -7,7 +7,10 @@ One dependency-free :class:`Recorder` collects everything a run emits:
   :class:`Span` always carries ``duration_s`` — even on a disabled recorder —
   so callers can use spans as their *only* timing primitive (the deployment
   engine's stage times and ``PlacementResult.wall_time_s`` are span
-  durations).
+  durations). Every span, stored or not, also opens a
+  ``jax.profiler.TraceAnnotation`` named :data:`TRACE_PREFIX` + its name
+  (``repro:deploy.place``) on the calling thread, so a profiler trace shows
+  the program's own regions on the device's clock.
 * **events** — ``rec.event("sa.iter", cost=..., accepted=True)``: the
   per-iteration search-trajectory telemetry the optimizers emit.
 * **counters / gauges / histograms** — ``rec.count("noc_batch.dispatch")``,
@@ -23,17 +26,36 @@ Export formats:
   ``chrome://tracing`` / Perfetto-loadable ``traceEvents`` JSON: spans as
   complete ("X") events, counters as "C" samples, point events as instants.
 
-The disabled path is zero-overhead by construction: every instrumentation
-site in the hot loops is guarded by ``if recorder is not None`` (the hooks
-thread ``recorder=None`` by default), and :func:`maybe_span` degrades to a
-bare perf_counter pair.
+The disabled path stores nothing: every instrumentation site in the hot
+loops is guarded by ``if recorder is not None`` (the hooks thread
+``recorder=None`` by default), and :func:`maybe_span` degrades to a
+perf_counter pair plus the profiler annotation. A detached span is not
+free: it still opens that annotation, a few microseconds a span when no
+profiler session runs (README gives the measured cost); a process that has
+not loaded jax opens none.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
+import sys
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
+
+#: prefix of every span's name on the profiler trace; a trace reducer
+#: selects the program's spans by it and ignores jax's own host events
+TRACE_PREFIX = "repro:"
+
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler annotation of the span ``name``. A process that has not
+    loaded jax has no profiler session to write into: nothing is opened."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(TRACE_PREFIX + name)
 
 
 @dataclasses.dataclass
@@ -76,18 +98,19 @@ class Recorder:
         exit whether or not the recorder is enabled."""
         sp = Span(name, t_start_s=self._now(), attrs=attrs or None)
         self._depth += 1
-        t0 = self._clock()
-        try:
-            yield sp
-        finally:
-            sp.duration_s = self._clock() - t0
-            self._depth -= 1
-            if self.enabled:
-                ev = {"kind": "span", "name": name, "ts": sp.t_start_s,
-                      "dur": sp.duration_s, "depth": self._depth}
-                if attrs:
-                    ev["attrs"] = attrs
-                self.events.append(ev)
+        with _annotation(name):
+            t0 = self._clock()
+            try:
+                yield sp
+            finally:
+                sp.duration_s = self._clock() - t0
+                self._depth -= 1
+                if self.enabled:
+                    ev = {"kind": "span", "name": name, "ts": sp.t_start_s,
+                          "dur": sp.duration_s, "depth": self._depth}
+                    if attrs:
+                        ev["attrs"] = attrs
+                    self.events.append(ev)
 
     # ---- point events -----------------------------------------------------
     def event(self, name: str, **attrs) -> None:
@@ -207,17 +230,19 @@ NULL_RECORDER = Recorder(enabled=False)
 @contextmanager
 def maybe_span(recorder: Recorder | None, name: str, **attrs):
     """``recorder.span`` when a recorder is attached, else a plain timed
-    :class:`Span` (no storage) — the idiom for optional instrumentation."""
+    :class:`Span` (no storage, profiler annotation still opened) — the idiom
+    for optional instrumentation."""
     if recorder is not None:
         with recorder.span(name, **attrs) as sp:
             yield sp
         return
     sp = Span(name)
-    t0 = time.perf_counter()
-    try:
-        yield sp
-    finally:
-        sp.duration_s = time.perf_counter() - t0
+    with _annotation(name):
+        t0 = time.perf_counter()
+        try:
+            yield sp
+        finally:
+            sp.duration_s = time.perf_counter() - t0
 
 
 # ---------------------------------------------------------------------------
@@ -242,8 +267,9 @@ def timed(fn, *args, **kw):
 
 def percentiles(samples, qs=(50, 99)) -> dict:
     """{min, max, mean, p50, p99, ...} over a sample list — the
-    latency-percentile summary the benchmark suites and the future placement
-    service report (dependency-light: plain sorted-list interpolation)."""
+    latency-percentile summary the benchmark suites and the placement
+    service's ``/stats`` report (dependency-light: plain sorted-list
+    interpolation)."""
     xs = sorted(float(x) for x in samples)
     if not xs:
         raise ValueError("percentiles() needs at least one sample")

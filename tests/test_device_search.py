@@ -132,6 +132,32 @@ def test_device_sa_valid_and_improves():
     assert _comm(noc, g, p) < _comm(noc, g, zigzag(g.n, noc))
 
 
+def test_device_sa_phases_within_the_search():
+    """The three phase spans come back through ``phases_s`` and through
+    ``optimize_placement``'s result, lie within the search's wall time,
+    and change nothing of the plan."""
+    import time
+
+    from repro.core.placement.device_search import SA_PHASES
+    noc = NoC(4, 4)
+    g = _int_graph(12, seed=3)
+    phases = {}
+    t0 = time.perf_counter()
+    p = simulated_annealing_device(g, noc, iters=200, seed=0, restarts=2,
+                                   phases_s=phases)
+    wall = time.perf_counter() - t0
+    assert tuple(phases) == SA_PHASES
+    assert all(v >= 0.0 for v in phases.values())
+    assert sum(phases.values()) <= wall
+    np.testing.assert_array_equal(
+        p, simulated_annealing_device(g, noc, iters=200, seed=0, restarts=2))
+    res = optimize_placement(g, noc, method="sa", backend="device",
+                             budget=200, restarts=2)
+    assert tuple(res.phases_s) == SA_PHASES
+    assert sum(res.phases_s.values()) <= res.wall_time_s
+    np.testing.assert_array_equal(res.placement, p)
+
+
 def test_device_sa_deterministic_and_restarts_monotone():
     noc = NoC(4, 8)
     g = _int_graph(28, seed=5)

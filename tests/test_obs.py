@@ -51,6 +51,45 @@ def test_null_recorder_and_maybe_span():
     assert sp2.duration_s >= 0.0
 
 
+def test_span_opens_a_prefixed_trace_annotation(monkeypatch):
+    """Every span, stored or detached, is a ``repro:``-prefixed profiler
+    annotation that opens and closes around the block on its thread."""
+    import threading
+
+    import jax.profiler
+
+    from repro.obs import TRACE_PREFIX
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name, threading.get_ident()))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name, threading.get_ident()))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    rec = Recorder()
+    with rec.span("deploy.place"):
+        log.append(("body",))
+    with Recorder(enabled=False).span("deploy.schedule"):
+        pass
+    with maybe_span(None, "queue.window"):
+        pass
+    me = threading.get_ident()
+    assert TRACE_PREFIX == "repro:"
+    assert log == [("open", "repro:deploy.place", me), ("body",),
+                   ("close", "repro:deploy.place", me),
+                   ("open", "repro:deploy.schedule", me),
+                   ("close", "repro:deploy.schedule", me),
+                   ("open", "repro:queue.window", me),
+                   ("close", "repro:queue.window", me)]
+    assert [e["name"] for e in rec.events] == ["deploy.place"]
+
+
 def test_counter_and_gauge_semantics():
     rec = Recorder()
     rec.count("c")

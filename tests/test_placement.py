@@ -89,6 +89,28 @@ def test_ppo_improves_over_iterations():
     assert len(set(st_.best_placement.tolist())) == g.n
 
 
+def test_ppo_phases_within_the_search():
+    """Each iteration's four phase spans are summed into ``phases_s``, lie
+    within the search's wall time, and reach the deployment report."""
+    import time
+
+    from repro.core.placement.ppo import PPO_PHASES
+    g = random_dag(10, seed=4)
+    noc = NoC(4, 4)
+    t0 = time.perf_counter()
+    st_ = run_ppo(g, noc, PPOConfig(batch_size=8, iterations=3,
+                                    ppo_epochs=2, seed=0))
+    wall = time.perf_counter() - t0
+    assert tuple(st_.phases_s) == PPO_PHASES
+    assert all(v > 0.0 for v in st_.phases_s.values())
+    assert sum(st_.phases_s.values()) <= wall
+    res = optimize_placement(g, noc, method="ppo", budget=3, batch_size=8,
+                             ppo_epochs=2)
+    assert tuple(res.phases_s) == PPO_PHASES
+    assert sum(res.phases_s.values()) <= res.wall_time_s
+    np.testing.assert_array_equal(res.placement, st_.best_placement)
+
+
 def test_ppo_freeze_gcn_keeps_gcn_params():
     """Paper: the GCN encoder is pre-trained and not updated by PPO."""
     import jax

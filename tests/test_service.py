@@ -382,6 +382,20 @@ def test_microbatch_queue_batches_and_propagates_errors():
     q.close()
 
 
+def test_microbatch_queue_lone_item_waits_the_window():
+    """With nothing else queued the batch never fills: the item waits the
+    whole window, and submit_timed says so."""
+    q = MicroBatchQueue(lambda items: [x + 1 for x in items], max_batch=8,
+                        window_s=0.03)
+    try:
+        result, wait_s = q.submit_timed(1, timeout=10)
+        assert result == 2
+        assert 0.03 <= wait_s < 5.0
+        assert q.submit(5, timeout=10) == 6
+    finally:
+        q.close()
+
+
 def test_microbatch_queue_result_count_mismatch():
     q = MicroBatchQueue(lambda items: [1, 2, 3], window_s=0.0)
     with pytest.raises(RuntimeError, match="returned 3 results"):
@@ -464,3 +478,76 @@ def test_http_concurrent_posts_micro_batch():
         server.shutdown()
         server.server_close()
         queue.close()
+
+
+def test_http_answer_carries_queue_wait():
+    svc = PlacementService()
+    server, queue = make_server(svc, port=0, window_s=0.02)
+    thread = threading.Thread(target=server.serve_forever, daemon=True)
+    thread.start()
+    url = f"http://127.0.0.1:{server.server_address[1]}"
+    try:
+        resp = request_over_http(url, _req(seed=3, budget=60))
+        assert 0.02 <= resp.queue_s < resp.latency_s + 5.0
+        assert resp.report["search_phases_s"] == {}   # host SA times none
+        assert resp.report["compiles"] >= 0
+    finally:
+        server.shutdown()
+        server.server_close()
+        queue.close()
+    # in-process answers waited in no queue
+    assert svc.submit(_req(seed=3, budget=60)).queue_s == 0.0
+
+
+def test_service_recorder_stays_bounded():
+    """The service recorder keeps counters and histograms only: its event
+    list does not grow with the requests it serves."""
+    svc = PlacementService()
+    svc.submit_batch([_req(seed=s, budget=60, method="random_search")
+                      for s in (5, 6)])                   # fused
+    svc.submit(_req(seed=0, budget=60))                   # warm
+    svc.submit(_req(seed=0, budget=60))                   # hit
+    svc.submit(_req(seed=1, budget=60))                   # warm
+    assert svc.recorder.events == []
+    c = svc.stats()["counters"]
+    assert c["service.requests"] == 5 and c["service.fused_batches"] == 1
+    assert c["service.hits"] == 1 and c["service.warm_starts"] == 2
+
+
+def test_compiles_counted_per_request():
+    """A device-SA request with an unseen seed compiles (the seed is a
+    static argument of the chain keys); its exact repeat is a hit and
+    compiles nothing. ``/stats`` counts every compile once."""
+    svc = PlacementService()
+    req = _req(seed=918273, budget=40, method="simulated_annealing",
+               backend="device", method_kw={"restarts": 2})
+    miss = svc.submit(req)
+    hit = svc.submit(req)
+    assert miss.status == "miss" and hit.status == "hit"
+    assert miss.report["compiles"] >= 1
+    assert hit.report["compiles"] == 0
+    assert tuple(miss.report["search_phases_s"]) == (
+        "sa.prepare", "sa.run", "sa.select")
+    assert svc.stats()["counters"]["service.compiles"] == \
+        miss.report["compiles"]
+
+
+def test_search_phases_are_the_requests_own():
+    """An answer's ``search_phases_s`` times its own search: a miss's
+    phases, a warm start's summed over its attempts, none for a hit. The
+    cached report stores none, so a hit cannot repeat another's."""
+    svc = PlacementService()
+    kw = dict(budget=40, method="simulated_annealing", backend="device",
+              method_kw={"restarts": 2})
+    miss = svc.submit(_req(seed=818273, **kw))
+    hit = svc.submit(_req(seed=818273, **kw))
+    warm = svc.submit(_req(seed=818274, **kw))
+    assert (miss.status, hit.status, warm.status) == ("miss", "hit", "warm")
+    phases = ("sa.prepare", "sa.run", "sa.select")
+    assert tuple(miss.report["search_phases_s"]) == phases
+    assert hit.report["search_phases_s"] == {}
+    assert tuple(warm.report["search_phases_s"]) == phases
+    assert 0.0 < sum(warm.report["search_phases_s"].values()) \
+        <= warm.latency_s
+    assert all("search_phases_s" not in e["report"]
+               for e in svc.cache.entries())
