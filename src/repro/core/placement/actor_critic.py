@@ -69,11 +69,19 @@ def sample_actions(key, mu, log_std, n_samples: int):
     return acts, logp
 
 
-def gaussian_logp(acts, mu, log_std):
-    """Sum of diagonal-Gaussian log-densities over nodes and dims: [B]."""
+def gaussian_logp(acts, mu, log_std, half_log_2pi=None):
+    """Sum of diagonal-Gaussian log-densities over nodes and dims: [B].
+
+    ``half_log_2pi`` is the normalizing constant ½·log 2π, computed here when
+    not given. Computed here, it is folded on the host when this runs
+    compiled and computed on the device when it runs op by op; on a TPU the
+    two differ in the last bits, so a compiled caller that must match the
+    op-by-op result passes the device's value in."""
+    if half_log_2pi is None:
+        half_log_2pi = 0.5 * jnp.log(2 * jnp.pi)
     std = jnp.exp(log_std)
     z = (acts - mu[None]) / std[None]
-    per = -0.5 * z ** 2 - log_std[None] - 0.5 * jnp.log(2 * jnp.pi)
+    per = -0.5 * z ** 2 - log_std[None] - half_log_2pi
     return per.sum(axis=(1, 2))
 
 

@@ -59,6 +59,9 @@ class PlacementResult:
     #: seconds per search phase (``ppo.*``, ``sa.*``); empty for methods
     #: that time none
     phases_s: dict = dataclasses.field(default_factory=dict)
+    #: work counts of the search (``ppo.sample.programs``); empty for
+    #: methods that count none
+    counters: dict = dataclasses.field(default_factory=dict)
 
     def summary(self) -> dict:
         return {
@@ -190,6 +193,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
     """
     history = None
     phases: dict = {}
+    counters: dict = {}
     method = METHOD_ALIASES.get(method, method)
     validate_method_kw(method, kw, backend=backend)
     bk = backend or "batch"
@@ -295,7 +299,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
                 cfg = _override_cfg(cfg, backend, objective)
             st = run_ppo(graph, noc, cfg, recorder=recorder)
             placement, history = st.best_placement, st.history
-            phases = st.phases_s
+            phases, counters = st.phases_s, st.counters
             ob = cfg.objective
         else:
             raise ValueError(f"unknown method {method!r}; "
@@ -320,7 +324,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
         throughput=m.throughput, max_link=m.max_link,
         wall_time_s=sp.duration_s, history=history,
         objective=obj.name, objective_cost=obj.from_metrics(m, noc, placement),
-        phases_s=phases)
+        phases_s=phases, counters=counters)
 
 
 def _reject_cfg_extras(method, cfg, kw):

@@ -9,10 +9,12 @@ Paper hyperparameters (§5.1): gcn feature size 32, batch 256, lr 0.005,
 ppo_epochs 10, clip 0.1–0.5, reward clip [-10, 10]. Defaults below mirror them but are
 all overridable; tests use smaller batches.
 
-The pipeline is batched end-to-end: rollouts are discretized by the vectorized
-resolver (`discretize_batch`, bit-exact vs the sequential spiral), scored in one
-`noc_batch` call, and all ``ppo_epochs`` inner epochs run as a single jitted
-``lax.scan`` dispatch (`_ppo_update_scan`) with rollout tensors device-resident.
+The pipeline is batched end-to-end: rollouts are drawn by two compiled
+programs (`_sample`, bit-exact vs the op-by-op actor forward and sampling),
+discretized by the vectorized resolver (`discretize_batch`, bit-exact vs the
+sequential spiral), scored in one `noc_batch` call, and all ``ppo_epochs`` inner
+epochs run as a single jitted ``lax.scan`` dispatch (`_ppo_update_scan`) with
+rollout tensors device-resident.
 Benchmarked in ``benchmarks/ppo_pipeline.py``.
 
 ``noc`` is any grid :class:`repro.core.topology.Topology` (the continuous
@@ -126,6 +128,44 @@ def _ppo_update_scan(actor, critic, opt_a, opt_c, lap, feats, acts, logp_old,
     return actor, critic, opt_a, opt_c, las[-1], lcs[-1]
 
 
+@partial(jax.jit, static_argnames=("n_samples",))
+def _sample_draw(key, actor, lap, feats, n_samples: int):
+    """The sample phase up to the noise, as one program: the key split, the
+    actor forward, ``std`` and the normal draw. Returns
+    ``(key, mu, log_std, std, eps)``; ``key`` carries to the next iteration.
+
+    The cut is set by float rounding, not by taste: one ulp in an action
+    moves the discretized plan, and compiled, each piece here matches the
+    eager ops bit for bit on the CPU and the TPU. ``mu + std * eps`` does
+    not on the CPU (compiled, alone or fused with the actor, it rounds
+    differently from the eager product and sum), so :func:`_sample` forms
+    the actions eagerly between this program and :data:`_sample_logp`."""
+    key, k_s = jax.random.split(key)
+    mu, log_std = ac.actor_apply(actor, lap, feats)
+    std = jnp.exp(log_std)
+    eps = jax.random.normal(k_s, (n_samples,) + mu.shape)
+    return key, mu, log_std, std, eps
+
+
+#: the log-density of the sampled actions, one program; its ½·log 2π comes
+#: in as an argument (see ``ac.gaussian_logp``)
+_sample_logp = jax.jit(ac.gaussian_logp)
+
+#: compiled programs :func:`_sample` dispatches (``_sample_draw`` and
+#: ``_sample_logp``), counted per iteration in ``ppo.sample.programs``
+SAMPLE_PROGRAMS = 2
+
+
+def _sample(key, actor, lap, feats, n_samples: int, half_log_2pi):
+    """One iteration's rollouts: ``(key, acts [B, n, 2], logp [B])``, bit for
+    bit the eager split → ``actor_apply`` → ``sample_actions``, given
+    ``half_log_2pi = 0.5 * jnp.log(2 * jnp.pi)`` computed op by op."""
+    key, mu, log_std, std, eps = _sample_draw(key, actor, lap, feats,
+                                              n_samples)
+    acts = mu[None] + std[None] * eps         # eager: see _sample_draw
+    return key, acts, _sample_logp(acts, mu, log_std, half_log_2pi)
+
+
 #: the phases of one PPO iteration, each a span; ``PPOState.phases_s`` sums
 #: each over the iterations
 PPO_PHASES = ("ppo.sample", "ppo.discretize", "ppo.score", "ppo.update")
@@ -141,6 +181,8 @@ class PPOState:
     best_cost: float
     best_placement: np.ndarray
     phases_s: dict = dataclasses.field(default_factory=dict)
+    #: work counts of the search (``ppo.sample.programs``)
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
@@ -157,7 +199,10 @@ def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
     sampling, up to the actions on the host), ``ppo.discretize``,
     ``ppo.score`` (host scoring) and ``ppo.update`` (rewards, the fused
     update dispatch, up to the losses on the host). ``phases_s`` of the
-    returned state sums each over the iterations."""
+    returned state sums each over the iterations; its ``counters`` hold
+    ``ppo.sample.programs``, the compiled programs the sample phase
+    dispatched (:data:`SAMPLE_PROGRAMS` an iteration), also counted on
+    ``recorder``."""
     key = jax.random.PRNGKey(cfg.seed)
     lap = jnp.asarray(graph.laplacian(), jnp.float32)
     feats = jnp.asarray(graph.node_features(), jnp.float32)
@@ -186,13 +231,17 @@ def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
     best_cost, best_placement = np.inf, None
     history = []
     phases = dict.fromkeys(PPO_PHASES, 0.0)
+    counters = {"ppo.sample.programs": 0}
+    # on the device, as the op-by-op sampling computed it each iteration
+    half_log_2pi = 0.5 * jnp.log(2 * jnp.pi)
     for it in range(cfg.iterations):
         with maybe_span(recorder, "ppo.sample") as sp:
-            key, k_s = jax.random.split(key)
-            mu, log_std = ac.actor_apply(actor, lap, feats)
-            acts, logp_old = ac.sample_actions(k_s, mu, log_std,
-                                               cfg.batch_size)
+            key, acts, logp_old = _sample(key, actor, lap, feats,
+                                          cfg.batch_size, half_log_2pi)
             acts_np = np.asarray(acts, np.float64)
+            counters["ppo.sample.programs"] += SAMPLE_PROGRAMS
+            if recorder is not None:
+                recorder.count("ppo.sample.programs", SAMPLE_PROGRAMS)
         phases["ppo.sample"] += sp.duration_s
         with maybe_span(recorder, "ppo.discretize") as sp:
             if resolver is not None:
@@ -234,4 +283,4 @@ def run_ppo(graph, noc, cfg: PPOConfig = PPOConfig(), baseline_cost=None,
         if recorder is not None:
             recorder.event("ppo.iter", **history[-1])
     return PPOState(actor, critic, opt_a, opt_c, history, float(best_cost),
-                    best_placement, phases)
+                    best_placement, phases, counters)

@@ -30,8 +30,9 @@ and benchmarks); :func:`make_server` wraps it in a stdlib
 connections through a :class:`repro.launch.serve.MicroBatchQueue` — the same
 continuous-batching idiom as the token server. Per-request latencies land in
 the service :class:`repro.obs.Recorder` as ``service.latency_s`` histograms
-(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts as
-counters; the recorder stores no spans. The served path's spans
+(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts and a
+cold search's own work counts (``ppo.sample.programs``) as counters; the
+recorder stores no spans. The served path's spans
 (``http.deploy``, ``queue.window``, ``service.batch``, ``service.deploy``,
 the engine's ``deploy.*`` stages and the searches' phases) are profiler
 annotations only. Each answer carries its own ``latency_s``, ``queue_s``,
@@ -267,6 +268,8 @@ class PlacementService:
             model, noc = self._materialize(request)
             plan = execute_request(request, model=model, noc=noc)
         rec.count("service.misses")
+        for name, n in plan.placement.counters.items():
+            rec.count(name, n)
         entry = self.cache.put(request, plan)
         return self._finish(entry, "miss", t0, c0, plan.placement.phases_s)
 

@@ -111,6 +111,76 @@ def test_ppo_phases_within_the_search():
     np.testing.assert_array_equal(res.placement, st_.best_placement)
 
 
+def _sresnet18_graph():
+    """The PPO cell's placement graph: S-ResNet18 cut into 32 slices."""
+    from repro.core.partition import partition_model
+    from repro.snn import profile_model, spike_resnet18
+    prof = profile_model(spike_resnet18(n_classes=10, in_res=32, T=4),
+                         batch=8, training=True)
+    return partition_model(prof, 32, "balanced").to_graph()
+
+
+def _eager_sample(key, actor, lap, feats, n_samples, half_log_2pi=None):
+    """The sample phase op by op, as run_ppo ran it before it was compiled
+    (``ac.gaussian_logp`` computes its own ½·log 2π)."""
+    from repro.core.placement import actor_critic as ac
+    import jax
+    key, k_s = jax.random.split(key)
+    mu, log_std = ac.actor_apply(actor, lap, feats)
+    acts, logp = ac.sample_actions(k_s, mu, log_std, n_samples)
+    return key, acts, logp
+
+
+@pytest.mark.parametrize("shape", ["sresnet18_b256", "dag10_b8"])
+def test_ppo_sample_bitwise_equals_eager(shape):
+    """The compiled sample phase (``_sample``) returns the eager path's keys,
+    actions and log-probs bit for bit over 40 iterations: one ulp in an
+    action can move the discretized plan."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.placement import actor_critic as ac
+    from repro.core.placement.ppo import _sample
+    if shape == "sresnet18_b256":
+        g, batch = _sresnet18_graph(), 256
+    else:
+        g, batch = random_dag(10, seed=4), 8
+    lap = jnp.asarray(g.laplacian(), jnp.float32)
+    feats = jnp.asarray(g.node_features(), jnp.float32)
+    actor, _ = ac.init_actor_critic(jax.random.PRNGKey(0), feats.shape[1],
+                                    32, 64)
+    k_ref = k_new = jax.random.PRNGKey(7)
+    half_log_2pi = 0.5 * jnp.log(2 * jnp.pi)      # as run_ppo computes it
+    for it in range(40):
+        k_ref, a_ref, l_ref = _eager_sample(k_ref, actor, lap, feats, batch)
+        k_new, a_new, l_new = _sample(k_new, actor, lap, feats, batch,
+                                      half_log_2pi)
+        assert np.array_equal(k_ref, k_new), it
+        assert np.array_equal(a_ref, a_new), it
+        assert np.array_equal(l_ref, l_new), it
+        # new weights each iteration, as the update gives them
+        actor = jax.tree_util.tree_map(lambda x: x * 1.01, actor)
+
+
+def test_ppo_plan_unchanged_by_compiled_sampling(monkeypatch):
+    """run_ppo on the PPO cell's graph and fabric returns the plan and the
+    history of a run whose sample phase is the eager composition, and counts
+    the sample phase's compiled programs."""
+    from repro.core.placement import ppo
+    from repro.core.topology import parse_topology
+    from repro.obs import Recorder
+    g, noc = _sresnet18_graph(), parse_topology("mesh:4x8")
+    cfg = PPOConfig(batch_size=32, iterations=4, ppo_epochs=2, seed=0)
+    rec = Recorder()
+    new = run_ppo(g, noc, cfg, recorder=rec)
+    assert rec.counters["ppo.sample.programs"] == \
+        ppo.SAMPLE_PROGRAMS * cfg.iterations == 2 * cfg.iterations
+    assert new.counters == {"ppo.sample.programs": 2 * cfg.iterations}
+    monkeypatch.setattr(ppo, "_sample", _eager_sample)
+    ref = run_ppo(g, noc, cfg)
+    np.testing.assert_array_equal(new.best_placement, ref.best_placement)
+    assert new.history == ref.history
+
+
 def test_ppo_freeze_gcn_keeps_gcn_params():
     """Paper: the GCN encoder is pre-trained and not updated by PPO."""
     import jax

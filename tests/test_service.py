@@ -532,6 +532,17 @@ def test_compiles_counted_per_request():
         miss.report["compiles"]
 
 
+def test_search_counters_reach_stats():
+    """A cold PPO search's work counts reach the service's ``/stats``
+    counters: two compiled sample programs an iteration."""
+    svc = PlacementService()
+    req = _req(seed=0, budget=3, method="ppo",
+               method_kw={"batch_size": 8, "ppo_epochs": 2})
+    assert svc.submit(req).status == "miss"
+    assert svc.submit(req).status == "hit"
+    assert svc.stats()["counters"]["ppo.sample.programs"] == 2 * 3
+
+
 def test_search_phases_are_the_requests_own():
     """An answer's ``search_phases_s`` times its own search: a miss's
     phases, a warm start's summed over its attempts, none for a hit. The
