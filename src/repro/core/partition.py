@@ -38,9 +38,22 @@ score partition-induced interchip traffic *before* any placement and
 optimizers can seed searches with chip-respecting initializations.
 
 ``Partition.to_graph()`` lowers a partition to the weighted logical DAG consumed by the
-placement optimizer: slice s of layer l multicasts its activation shard to every slice
-of layer l+1 (K-split consumers need the full input), which is exactly the multicast
-node feature the RL state encodes.
+placement optimizer, one edge set per (producer, consumer) pair of layer units. A unit
+reads the units its :attr:`LayerProfile.producers` names, or the previous unit when it
+names none (so a convolution stack is a chain), in one of two ways:
+
+* ``"full"`` — a contraction over input channels: every slice of the producer
+  multicasts its whole activation shard to every slice of the consumer (K-split
+  consumers need the full input), which is exactly the multicast node feature the RL
+  state encodes;
+* ``"aligned"`` — per channel or per head (attention's Q/K/V, a residual operand):
+  producer slice i sends to consumer slice j only where their channel ranges overlap,
+  as fractions of each unit's ``c_out``; the volume is the producer's ``out_bytes`` ×
+  that overlap, exact from the integer channel bounds.
+
+When units outnumber cores, contiguous units merge into one slice each; an edge inside
+a group is dropped, and each producer tensor that crosses into a group is sent to it
+once. The chip-aware strategies handle chains only and refuse a profile with branches.
 """
 from __future__ import annotations
 
@@ -60,6 +73,9 @@ class LayerProfile:
     out_bytes: float          # activation bytes produced per sample
     c_in: int = 1
     c_out: int = 1
+    #: ((producer unit name, "full" | "aligned"), ...); () = the previous unit,
+    #: full (see the module docstring)
+    producers: tuple = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +109,10 @@ class Partition:
     core: CoreSpec
     strategy: str
     chip_of: np.ndarray | None = None   # [n] slice -> chip (chip-aware only)
+    #: ((producer layer, consumer layer, kind, producer bytes, producer
+    #: channels, consumer channels), ...) between the partitioned units;
+    #: None = a chain (each layer reads the last, full)
+    unit_edges: tuple | None = None
 
     @property
     def n(self) -> int:
@@ -135,12 +155,28 @@ class Partition:
         by_layer: dict = {}
         for idx, s in enumerate(self.slices):
             by_layer.setdefault(s.layer, []).append(idx)
-        layers = sorted(by_layer)
-        for a, b in zip(layers[:-1], layers[1:]):
-            for i in by_layer[a]:
-                for j in by_layer[b]:
-                    # K-split consumer needs the producer's full activation shard
-                    adj[i, j] = self.slices[i].out_bytes
+        edges = self.unit_edges
+        if edges is None:
+            layers = sorted(by_layer)
+            edges = [(a, b, "full", None, 0, 0)
+                     for a, b in zip(layers[:-1], layers[1:])]
+        for a, b, kind, vol, ca, cb in edges:
+            if kind == "full":
+                # K-split consumer needs the producer's full activation shard
+                for i in by_layer[a]:
+                    s = self.slices[i]
+                    v = s.out_bytes if vol is None else vol * s.frac
+                    for j in by_layer[b]:
+                        adj[i, j] += v
+                continue
+            src = list(zip(by_layer[a], _channel_bounds(ca, len(by_layer[a]))))
+            dst = list(zip(by_layer[b], _channel_bounds(cb, len(by_layer[b]))))
+            for i, (lo, hi) in src:
+                for j, (lo2, hi2) in dst:
+                    # overlap of [lo, hi)/ca and [lo2, hi2)/cb, over ca * cb
+                    num = min(hi * cb, hi2 * ca) - max(lo * cb, lo2 * ca)
+                    if num > 0:
+                        adj[i, j] += vol * num / (ca * cb)
         compute = np.array([s.flops for s in self.slices])
         memory = np.array([s.weight_bytes for s in self.slices])
         return LogicalGraph(adj, compute, memory,
@@ -214,6 +250,59 @@ def _slice_layer(li: int, layer: LayerProfile, n_slices: int) -> list:
             out_bytes=layer.out_bytes * frac,
         ))
     return out
+
+
+def _channel_bounds(c_out: int, n_slices: int) -> list:
+    """``[(lo, hi), ...]``: the output channels of each slice of
+    :func:`_slice_layer`'s even K-split."""
+    base, extra = divmod(c_out, n_slices)
+    out, lo = [], 0
+    for s in range(n_slices):
+        hi = lo + base + (1 if s < extra else 0)
+        out.append((lo, hi))
+        lo = hi
+    return out
+
+
+def _unit_edges(layers) -> tuple | None:
+    """``((a, b, kind, bytes, c_out of a, c_out of b), ...)`` for every
+    producer of every unit, in consumer order; None when the profile is a
+    chain (every unit reads just the previous one, full), which keeps the
+    historical graph path."""
+    index = {l.name: i for i, l in enumerate(layers)}
+    edges, chain = [], True
+    for b, layer in enumerate(layers):
+        prods = layer.producers
+        if not prods:
+            prods = ((layers[b - 1].name, "full"),) if b else ()
+        for name, kind in prods:
+            a = index.get(name)
+            if a is None or a >= b:
+                raise ValueError(f"unit {layer.name!r}: producer {name!r} is "
+                                 "not an earlier unit of the profile")
+            if kind not in ("full", "aligned"):
+                raise ValueError(f"unit {layer.name!r}: producer kind "
+                                 f"{kind!r} is not 'full' or 'aligned'")
+            chain &= a == b - 1 and kind == "full" and len(prods) == 1
+            edges.append((a, b, kind, layers[a].out_bytes,
+                          layers[a].c_out, layer.c_out))
+    return None if chain else tuple(edges)
+
+
+def _group_edges(edges: tuple, groups: list) -> tuple:
+    """The unit edges between contiguous ``groups`` (one slice each): edges
+    inside a group are dropped, and each producer's tensor is sent to a
+    consumer group once, in full."""
+    group_of = np.empty(groups[-1][1], dtype=np.int64)
+    for g, (a, b) in enumerate(groups):
+        group_of[a:b] = g
+    out, seen = [], set()
+    for a, b, _, vol, _, _ in edges:
+        ga, gb = int(group_of[a]), int(group_of[b])
+        if ga != gb and (a, gb) not in seen:
+            seen.add((a, gb))
+            out.append((ga, gb, "full", vol, 0, 0))
+    return tuple(out)
 
 
 def _group_contiguous(weights: np.ndarray, k: int) -> list:
@@ -389,7 +478,14 @@ def partition_model(layers, n_cores: int, strategy: str = "balanced",
     bit-identical to the historical chip-oblivious path.
     """
     layers = list(layers)
+    edges = _unit_edges(layers)
     if strategy in CHIP_STRATEGIES:
+        if edges is not None:
+            raise ValueError(
+                f"strategy {strategy!r} allocates chains of layer units to "
+                "chips; this profile has branch edges (units with producers "
+                "other than the previous unit): use a flat strategy such as "
+                "'balanced'")
         if topology is None:
             raise ValueError(f"strategy {strategy!r} needs topology= "
                              "(the chip structure drives the allocation)")
@@ -404,6 +500,8 @@ def partition_model(layers, n_cores: int, strategy: str = "balanced",
         weights = np.array([_layer_weight(l, strategy, core) for l in layers])
         groups = _group_contiguous(weights, n_cores)
         layers = [_merge_group(layers, a, b) for a, b in groups]
+        if edges is not None:
+            edges = _group_edges(edges, groups)
     weights = np.array([_layer_weight(l, strategy, core) for l in layers])
     alloc = _alloc_largest_remainder(weights, n_cores)
 
@@ -413,7 +511,8 @@ def partition_model(layers, n_cores: int, strategy: str = "balanced",
     slices: list = []
     for li, (layer, k) in enumerate(zip(layers, alloc)):
         slices.extend(_slice_layer(li, layer, int(k)))
-    return Partition(slices=slices, core=core, strategy=strategy)
+    return Partition(slices=slices, core=core, strategy=strategy,
+                     unit_edges=edges)
 
 
 def _partition_chip_aware(layers, strategy: str, core: CoreSpec, topology,

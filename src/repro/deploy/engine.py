@@ -47,7 +47,8 @@ class DeploymentPlan:
     schedule_name: str
     schedule: object                 # pipeline.Schedule | None
     n_units: int
-    stage_times_s: dict              # {"profile"|"partition"|"place"|"schedule": s}
+    stage_times_s: dict              # {"profile"|"partition"|"graph"|"place"
+                                     #  |"schedule": s}; graph in partition
     contention_feedback: bool = False
     copartition_iters: int = 0       # co-design outer-loop rounds actually run
 
@@ -87,7 +88,32 @@ class DeploymentPlan:
                           "wall_time_s": float(r.wall_time_s)},
             "schedule": sched,
             "stage_times_s": dict(self.stage_times_s),
+            "graph": graph_stats(self.partition, self.graph),
         }
+
+
+def count_graph(recorder, stats: dict) -> None:
+    """Add one built graph's :func:`graph_stats` to ``recorder``'s counters:
+    ``deploy.graphs`` and the summed ``deploy.graph.nodes``, ``.edges`` and
+    ``.branch_edges`` (the largest degree is in the report only: a sum of
+    maxima means nothing)."""
+    recorder.count("deploy.graphs")
+    for key in ("nodes", "edges", "branch_edges"):
+        recorder.count(f"deploy.graph.{key}", stats[key])
+
+
+def graph_stats(part: Partition, graph) -> dict:
+    """``{nodes, edges, branch_edges, max_degree}`` of a partition's logical
+    graph: ``branch_edges`` counts the edges whose producer slice is not of
+    the consumer's previous unit; ``max_degree`` is the most edges incident
+    to one node (in + out), the width of the device search's incident
+    tables."""
+    src, dst, _ = graph.edge_arrays()
+    layer = np.array([s.layer for s in part.slices], dtype=np.int64)
+    deg = np.bincount(np.concatenate([src, dst]), minlength=graph.n)
+    return {"nodes": int(graph.n), "edges": int(src.size),
+            "branch_edges": int((layer[src] != layer[dst] - 1).sum()),
+            "max_degree": int(deg.max(initial=0))}
 
 
 def _profiles(model, batch: int, training: bool, spike_density: float):
@@ -335,7 +361,8 @@ def _deploy(model, noc, partition_strategy: str = "auto",
     with rec.span("deploy.partition", strategy=strategy) as sp_partition:
         part = partition_model(profiles, n_usable, strategy, core,
                                topology=noc)
-        graph = part.to_graph()
+        with rec.span("deploy.graph") as sp_graph:
+            graph = part.to_graph()
     if schedule == "one_f_one_b":
         # 1F1B needs n_micro >= n_stages for a full pipe; report the count
         # actually scheduled, not the request
@@ -401,12 +428,14 @@ def _deploy(model, noc, partition_strategy: str = "auto",
         sched = _schedule(times, schedule, n_units, bwd_ratio, training)
     stage_times = {"profile": sp_profile.duration_s,
                    "partition": sp_partition.duration_s,
+                   "graph": sp_graph.duration_s,
                    "place": sp_place.duration_s,
                    "schedule": sp_schedule.duration_s}
     if rounds_run:
         stage_times["copartition"] = sp_copart.duration_s
     if recorder is not None:
         recorder.count("deploy.deployments")
+        count_graph(recorder, graph_stats(part, graph))
     return DeploymentPlan(
         model=name, noc=noc, profiles=profiles, partition=part, graph=graph,
         placement=result, schedule_name=schedule, schedule=sched,

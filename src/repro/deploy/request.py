@@ -41,7 +41,9 @@ import numpy as np
 from ..core.partition import CoreSpec, LayerProfile
 from ..core.topology import (DegradedTopology, GridTopology, HierarchicalMesh,
                              Topology, degrade)
-from ..snn.models import (Classifier, ConvBNLif, MaxPool, Residual, SNNConfig)
+from ..snn.models import (SPS, Classifier, ConvBNLif, LinearBNLif, MaxPool,
+                          Residual, SNNConfig, SpikingSelfAttention,
+                          TransformerBlock)
 from ..snn.neurons import LIFConfig
 from .objective import EnergyModel, Objective, as_objective
 
@@ -69,6 +71,7 @@ class RequestEncodeError(TypeError):
 
 _DC_CLASSES = {cls.__name__: cls for cls in
                (SNNConfig, ConvBNLif, Residual, MaxPool, Classifier,
+                SPS, TransformerBlock, SpikingSelfAttention, LinearBNLif,
                 LIFConfig, CoreSpec, LayerProfile)}
 
 

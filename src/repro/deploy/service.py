@@ -30,13 +30,15 @@ and benchmarks); :func:`make_server` wraps it in a stdlib
 connections through a :class:`repro.launch.serve.MicroBatchQueue` — the same
 continuous-batching idiom as the token server. Per-request latencies land in
 the service :class:`repro.obs.Recorder` as ``service.latency_s`` histograms
-(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts and a
-cold search's own work counts (``ppo.sample.programs``) as counters; the
-recorder stores no spans. The served path's spans
-(``http.deploy``, ``queue.window``, ``service.batch``, ``service.deploy``,
-the engine's ``deploy.*`` stages and the searches' phases) are profiler
-annotations only. Each answer carries its own ``latency_s``, ``queue_s``,
-``report["search_phases_s"]`` and ``report["compiles"]``; its
+(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts, a
+cold search's own work counts (``ppo.sample.programs``) and the logical
+graphs its plans were made on (``deploy.graphs``, ``deploy.graph.nodes``,
+``.edges``, ``.branch_edges``) as counters; the recorder stores no spans.
+The served path's spans (``http.deploy``, ``queue.window``,
+``service.batch``, ``service.deploy``, the engine's ``deploy.*`` stages and
+the searches' phases) are profiler annotations only. Each answer carries
+its own ``latency_s``, ``queue_s``, ``report["search_phases_s"]`` and
+``report["compiles"]``; its
 ``report["stage_times_s"]`` is the cached plan's (a hit repeats the stage
 times of the search that made the plan).
 
@@ -58,7 +60,7 @@ import numpy as np
 from ..core.partition import partition_model
 from ..launch.serve import MicroBatchQueue
 from ..obs import Recorder, maybe_span
-from .engine import _profiles, execute_request
+from .engine import _profiles, count_graph, execute_request
 from .plancache import PlanCache, _obj_blob
 from .request import DeployRequest
 
@@ -261,6 +263,7 @@ class PlacementService:
             else:
                 rec.count("service.warm_starts")
                 entry = self.cache.put(request, plan)
+                count_graph(rec, entry["report"]["graph"])
                 return self._finish(entry, "warm", t0, c0, phases,
                                     warm_from=donor["cache_key"],
                                     attempts=attempts)
@@ -271,6 +274,7 @@ class PlacementService:
         for name, n in plan.placement.counters.items():
             rec.count(name, n)
         entry = self.cache.put(request, plan)
+        count_graph(rec, entry["report"]["graph"])
         return self._finish(entry, "miss", t0, c0, plan.placement.phases_s)
 
     def _finish(self, entry: dict, status: str, t0: float, c0: int,
@@ -382,6 +386,7 @@ class PlacementService:
             plan = execute_request(req, model=model, noc=noc,
                                    _fixed_placement=pl)
             entry = self.cache.put(req, plan)
+            count_graph(rec, entry["report"]["graph"])
             out.append(self._finish(entry, "miss", t0, c0, fused=True))
         return out
 

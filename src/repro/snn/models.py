@@ -1,4 +1,5 @@
-"""Spike-ResNet18 / Spike-VGG16 / Spike-ResNet50 (the paper's workloads, §5.1).
+"""The SNN model zoo: Spike-ResNet18 / Spike-VGG16 / Spike-ResNet50 (the paper's
+workloads, §5.1) and Spikformer (Zhou et al., ICLR 2023, arXiv:2209.15425).
 
 Architecture = descriptor list; ``model_specs`` / ``init_state`` / ``model_step`` all
 walk the same descriptors, so the profiler (`snn.profile`) and partitioner see exactly
@@ -7,6 +8,12 @@ per-layer LIF membrane states as carry (BPTT through time unrolls this scan).
 
 Reduced ("smoke") configs scale width/depth/resolution down so the full training step
 runs on CPU; the full configs match torchvision channel plans.
+
+Spikformer's descriptors (:class:`SPS`, :class:`TransformerBlock` with its
+:class:`SpikingSelfAttention` and :class:`LinearBNLif` units) are placement
+descriptors: ``model_specs`` sizes them and ``snn.profile`` profiles them, but
+the BPTT forward (``init_state`` / ``model_step``) is implemented for the
+convolution stacks only and raises ``NotImplementedError`` on them.
 """
 from __future__ import annotations
 
@@ -51,6 +58,47 @@ class Classifier:
     name: str
     din: int
     dout: int
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearBNLif:
+    """Token-wise Linear-BN-LIF: a 1x1 unit applied to each of the N tokens."""
+    name: str
+    din: int
+    dout: int
+
+
+@dataclasses.dataclass(frozen=True)
+class SpikingSelfAttention:
+    """SSA: Q, K, V = LIF(BN(Linear(X))); A = LIF(Q·Kᵀ·V·scale) per head, with
+    no softmax; output LIF(BN(Linear(A))) (``proj``), added to X."""
+    name: str
+    q: LinearBNLif
+    k: LinearBNLif
+    v: LinearBNLif
+    proj: LinearBNLif
+    heads: int
+    scale: float = 0.125
+
+
+@dataclasses.dataclass(frozen=True)
+class TransformerBlock:
+    """X' = X + SSA(X); X'' = X' + fc2(fc1(X'))."""
+    name: str
+    attn: SpikingSelfAttention
+    fc1: LinearBNLif
+    fc2: LinearBNLif
+
+
+@dataclasses.dataclass(frozen=True)
+class SPS:
+    """Spiking Patch Splitting: Conv-BN-LIF ``convs``, each followed by a 2x2
+    max-pool where ``pool_after`` says, then the relative-position conv
+    ``rpe``, whose output is added to its own input."""
+    name: str
+    convs: tuple              # tuple[ConvBNLif, ...]
+    pool_after: tuple         # tuple[bool, ...], one per conv
+    rpe: ConvBNLif
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,10 +180,54 @@ def spike_vgg16(n_classes=10, in_res=32, T=4, width_mult=1.0,
     return SNNConfig("spike-vgg16", tuple(blocks), n_classes, in_res, in_ch, T)
 
 
+def spikformer(depth=8, dim=768, heads=12, mlp_ratio=4, n_classes=1000,
+               in_res=224, in_ch=3, T=4, patch=16) -> SNNConfig:
+    """Spikformer-``depth``-``dim``: SPS (four 3x3 Conv-BN-LIF with channels
+    dim/8, dim/4, dim/2, dim, a 2x2 max-pool after each of the last
+    log2(``patch``), then ``rpe``), ``depth`` transformer blocks of ``heads``
+    heads and MLP ratio ``mlp_ratio``, global average pool and a linear head.
+    ``patch=16`` at 224x224 is the ImageNet model (N = 196 tokens);
+    ``patch=4`` at 32x32 the CIFAR one."""
+    n_pools = patch.bit_length() - 1
+    if patch < 1 or 1 << n_pools != patch or n_pools > 4:
+        raise ValueError(f"patch must be 1, 2, 4, 8 or 16, got {patch}")
+    if dim % 8 or dim % heads:
+        raise ValueError(f"dim {dim} must divide by 8 and by heads {heads}")
+    chans = (in_ch, dim // 8, dim // 4, dim // 2, dim)
+    convs = tuple(ConvBNLif(f"sps{i}", chans[i], chans[i + 1], 3, 1)
+                  for i in range(4))
+    pools = tuple(i >= 4 - n_pools for i in range(4))
+    blocks: list = [SPS("sps", convs, pools,
+                        ConvBNLif("rpe", dim, dim, 3, 1))]
+    hidden = dim * mlp_ratio
+    for b in range(depth):
+        lin = lambda n, i, o: LinearBNLif(f"b{b}{n}", i, o)  # noqa: E731
+        attn = SpikingSelfAttention(f"b{b}attn", lin("q", dim, dim),
+                                    lin("k", dim, dim), lin("v", dim, dim),
+                                    lin("proj", dim, dim), heads)
+        blocks.append(TransformerBlock(f"b{b}", attn, lin("fc1", dim, hidden),
+                                       lin("fc2", hidden, dim)))
+    blocks.append(Classifier("head", dim, n_classes))
+    return SNNConfig(f"spikformer-{depth}-{dim}", tuple(blocks), n_classes,
+                     in_res, in_ch, T)
+
+
 # ---- specs / state / step ----------------------------------------------------
 
 def _conv_unit_specs(u: ConvBNLif):
     return {"conv": L.conv_specs(u.cin, u.cout, u.k), "bn": L.bn_specs(u.cout)}
+
+
+def _linear_unit_specs(u: LinearBNLif):
+    return {"linear": L.linear_specs(u.din, u.dout), "bn": L.bn_specs(u.dout)}
+
+
+def _forward_unsupported(b) -> None:
+    if isinstance(b, (SPS, TransformerBlock)):
+        raise NotImplementedError(
+            f"{type(b).__name__} {b.name!r}: the BPTT forward covers the "
+            "convolution stacks only; Spikformer descriptors are for "
+            "profiling and placement")
 
 
 def model_specs(cfg: SNNConfig):
@@ -148,6 +240,13 @@ def model_specs(cfg: SNNConfig):
             if b.downsample is not None:
                 d[b.downsample.name] = _conv_unit_specs(b.downsample)
             out[b.name] = d
+        elif isinstance(b, SPS):
+            out[b.name] = {u.name: _conv_unit_specs(u)
+                           for u in b.convs + (b.rpe,)}
+        elif isinstance(b, TransformerBlock):
+            a = b.attn
+            out[b.name] = {u.name: _linear_unit_specs(u)
+                           for u in (a.q, a.k, a.v, a.proj, b.fc1, b.fc2)}
         elif isinstance(b, Classifier):
             out[b.name] = L.linear_specs(b.din, b.dout)
     return out
@@ -158,6 +257,7 @@ def _shapes(cfg: SNNConfig, batch: int):
     h = w = cfg.in_res
     shapes = {}
     for b in cfg.blocks:
+        _forward_unsupported(b)
         if isinstance(b, ConvBNLif):
             h = -(-h // b.stride)
             w = -(-w // b.stride)
@@ -200,6 +300,7 @@ def model_step(params, cfg: SNNConfig, state, x):
     h = x
     logits = None
     for b in cfg.blocks:
+        _forward_unsupported(b)
         if isinstance(b, ConvBNLif):
             h = _apply_unit(params[b.name], b, h, state, new_state, cfg.lif)
         elif isinstance(b, Residual):

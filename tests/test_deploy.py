@@ -220,8 +220,8 @@ def test_deploy_model_end_to_end():
     assert plan.model == "spike-resnet18"
     assert plan.partition.n == noc.n_cores
     assert plan.graph.n == plan.partition.n
-    assert sorted(plan.stage_times_s) == ["partition", "place", "profile",
-                                          "schedule"]
+    assert sorted(plan.stage_times_s) == ["graph", "partition", "place",
+                                          "profile", "schedule"]
     assert all(t >= 0 for t in plan.stage_times_s.values())
     assert plan.schedule.makespan > 0
     rep = plan.report()

@@ -402,3 +402,38 @@ def test_cli_sweep_trace_flag(tmp_path):
                for e in evs)
     ct = json.loads(chrome.read_text())
     assert ct["traceEvents"]
+
+
+def test_deploy_graph_span_report_and_parity():
+    """The graph lowering is its own span inside ``deploy.partition``, its
+    time a stage of its own, and the report counts its nodes and edges;
+    the plan is the same with the recorder on or off."""
+    from repro.snn import spikformer
+
+    model = spikformer(depth=2, dim=64, heads=4, mlp_ratio=4, n_classes=10,
+                       in_res=32, in_ch=3, T=4, patch=4)
+    noc = parse_topology("mesh:4x8")
+    kw = dict(method="simulated_annealing", budget=60, schedule="fpdeep",
+              partition_strategy="balanced", seed=3)
+    rec = Recorder()
+    plan = deploy_model(model, noc, recorder=rec, **kw)
+    spans = {e["name"]: e for e in rec.events if e["kind"] == "span"}
+    graph, part = spans["deploy.graph"], spans["deploy.partition"]
+    assert graph["depth"] == part["depth"] + 1
+    assert part["ts"] <= graph["ts"] and \
+        graph["ts"] + graph["dur"] <= part["ts"] + part["dur"]
+    assert 0.0 <= plan.stage_times_s["graph"] <= \
+        plan.stage_times_s["partition"]
+    src, dst, _ = plan.graph.edge_arrays()
+    layer = np.array([s.layer for s in plan.partition.slices])
+    stats = plan.report()["graph"]
+    assert stats == {
+        "nodes": 32, "edges": len(src),
+        "branch_edges": int((layer[src] != layer[dst] - 1).sum()),
+        "max_degree": int(np.bincount(np.concatenate([src, dst])).max())}
+    assert rec.counters["deploy.graphs"] == 1
+    assert rec.counters["deploy.graph.edges"] == len(src)
+    off = deploy_model(model, noc, **kw)
+    np.testing.assert_array_equal(off.placement.placement,
+                                  plan.placement.placement)
+    assert off.report()["graph"] == stats

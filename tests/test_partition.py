@@ -103,3 +103,124 @@ def test_spill_latency_model():
     pf = partition_model([fits], 1, "balanced", core)
     ps = partition_model([spills], 1, "balanced", core)
     assert ps.latencies()[0] > pf.latencies()[0]
+
+
+# ---------------------------------------------------------------------------
+# producer edges (branched profiles)
+# ---------------------------------------------------------------------------
+
+def _tiny_spikformer_profile(density=0.15):
+    from repro.snn import profile_model, spikformer
+
+    cfg = spikformer(depth=2, dim=64, heads=4, mlp_ratio=4, n_classes=10,
+                     in_res=32, in_ch=3, T=4, patch=4)
+    return profile_model(cfg, batch=8, spike_density=density)
+
+
+def _consecutive_graph(p):
+    """Every slice of layer l to every slice of layer l+1, the volume its
+    shard: the chain construction the producer edges must reproduce."""
+    n = p.n
+    adj = np.zeros((n, n))
+    for i, s in enumerate(p.slices):
+        for j, t in enumerate(p.slices):
+            if t.layer == s.layer + 1:
+                adj[i, j] = s.out_bytes
+    return adj
+
+
+@pytest.mark.parametrize("model,n_cores", [
+    ("spike_resnet18", 32), ("spike_resnet50", 64), ("spike_resnet50", 32),
+    ("spike_vgg16", 64)])
+def test_chain_graphs_bit_equal_consecutive_construction(model, n_cores):
+    import repro.snn as snn
+
+    prof = snn.profile_model(getattr(snn, model)(), batch=8)
+    assert all(l.producers == () for l in prof)
+    p = partition_model(prof, n_cores, "balanced")
+    assert p.unit_edges is None
+    np.testing.assert_array_equal(p.to_graph().adj, _consecutive_graph(p))
+
+
+def test_aligned_edge_volumes_sum_to_producer_out_bytes():
+    prof = _tiny_spikformer_profile()
+    p = partition_model(prof, 32, "balanced")
+    adj = p.to_graph().adj
+    layer = np.array([s.layer for s in p.slices])
+    aligned = [(a, b, vol) for a, b, kind, vol, _, _ in p.unit_edges
+               if kind == "aligned"]
+    assert len(aligned) == 2 * 5          # attn <- q, k, v; proj; fc2
+    for a, b, vol in aligned:
+        assert vol == prof[a].out_bytes
+        block = adj[np.ix_(layer == a, layer == b)]
+        assert block.sum() == pytest.approx(vol, rel=1e-12)
+        # each producer slice sends exactly its own shard
+        shard = [s.out_bytes for s in p.slices if s.layer == a]
+        np.testing.assert_allclose(block.sum(axis=1), shard, rtol=1e-12)
+
+
+def test_aligned_edges_follow_channel_overlap():
+    """8 channels in 3 slices (3, 3, 2) to 4 heads in 2 slices (2, 2):
+    each pair carries the producer's bytes x the overlap of their ranges."""
+    layers = [LayerProfile("x", 1e9, 0.0, 800.0, c_out=8),
+              LayerProfile("h", 1e9, 0.0, 100.0, c_out=4,
+                           producers=(("x", "aligned"),))]
+    p = partition_model(layers, 5, "compute")
+    assert [s.layer for s in p.slices] == [0, 0, 0, 1, 1]
+    want = np.zeros((5, 5))
+    want[0, 3], want[1, 3], want[1, 4], want[2, 4] = 300.0, 100.0, 200.0, 200.0
+    np.testing.assert_array_equal(p.to_graph().adj, want)
+
+
+def test_grouped_units_keep_producer_edges():
+    """20 units on 16 cores: contiguous groups of one slice each; an edge
+    inside a group is dropped, and each producer's tensor reaches another
+    group once, at its full volume."""
+    from repro.core.partition import _group_contiguous, _layer_weight
+
+    prof = _tiny_spikformer_profile()
+    core = CoreSpec()
+    p = partition_model(prof, 16, "balanced", core)
+    assert p.n == 16 and all(s.frac == 1.0 for s in p.slices)
+    groups = _group_contiguous(
+        np.array([_layer_weight(l, "balanced", core) for l in prof]), 16)
+    group_of = {}
+    for g, (a, b) in enumerate(groups):
+        for u in range(a, b):
+            group_of[prof[u].name] = g
+    want = np.zeros((16, 16))
+    sent = set()
+    for u, l in enumerate(prof):
+        prods = [n for n, _ in l.producers] or ([prof[u - 1].name] if u else [])
+        for name in prods:
+            ga, gb = group_of[name], group_of[l.name]
+            if ga != gb and (name, gb) not in sent:
+                sent.add((name, gb))
+                want[ga, gb] += next(x.out_bytes for x in prof
+                                     if x.name == name)
+    adj = p.to_graph().adj
+    np.testing.assert_array_equal(adj, want)
+    assert np.trace(adj) == 0.0
+
+
+def test_chip_strategies_refuse_branched_profiles():
+    from repro.core.topology import parse_topology
+
+    noc = parse_topology("hier:2x2:4x4")
+    for strategy in ("chip", "chip_balanced"):
+        with pytest.raises(ValueError, match="branch"):
+            partition_model(_tiny_spikformer_profile(), 64, strategy,
+                            topology=noc)
+
+
+@pytest.mark.parametrize("producers,match", [
+    ((("nope", "full"),), "not an earlier unit"),
+    ((("b", "full"),), "not an earlier unit"),
+    ((("a", "sideways"),), "kind"),
+])
+def test_bad_producers_rejected(producers, match):
+    layers = [LayerProfile("a", 1e9, 1e5, 1e3, c_out=8),
+              LayerProfile("b", 1e9, 1e5, 1e3, c_out=8,
+                           producers=producers)]
+    with pytest.raises(ValueError, match=match):
+        partition_model(layers, 4, "balanced")

@@ -562,3 +562,60 @@ def test_search_phases_are_the_requests_own():
         <= warm.latency_s
     assert all("search_phases_s" not in e["report"]
                for e in svc.cache.entries())
+
+
+# ---------------------------------------------------------------------------
+# a branched model: Spikformer through the request layer and the service
+# ---------------------------------------------------------------------------
+
+def _spikformer_req(**kw):
+    from repro.core.topology import parse_topology
+    from repro.snn import spikformer
+
+    model = spikformer(depth=2, dim=64, heads=4, mlp_ratio=4, n_classes=10,
+                       in_res=32, in_ch=3, T=4, patch=4)
+    kw.setdefault("method", "simulated_annealing")
+    kw.setdefault("schedule", "none")
+    return DeployRequest.from_call(model, parse_topology("mesh:4x8"),
+                                   partition_strategy="balanced", **kw)
+
+
+def test_spikformer_request_roundtrip_and_key():
+    req = _spikformer_req(budget=40, backend="device",
+                          method_kw={"restarts": 2})
+    back = DeployRequest.from_json(json.loads(json.dumps(req.to_json())))
+    assert back == req and back.cache_key() == req.cache_key()
+    assert back.materialize_model() == req.materialize_model()
+    assert _spikformer_req(budget=40, backend="device",
+                           method_kw={"restarts": 2}).cache_key() \
+        == req.cache_key()
+    assert _spikformer_req(budget=41, backend="device",
+                           method_kw={"restarts": 2}).cache_key() \
+        != req.cache_key()
+
+
+def test_service_serves_spikformer_with_device_sa():
+    svc = PlacementService()
+    req = _spikformer_req(budget=40, backend="device",
+                          method_kw={"restarts": 2})
+    resp = svc.submit(req)
+    assert resp.status == "miss"
+    p = np.asarray(resp.placement)
+    assert p.shape == (32,) and len(set(p.tolist())) == 32
+    assert p.min() >= 0 and p.max() < 32
+    plan = instantiate_plan(req, p)
+    src, dst, _ = plan.graph.edge_arrays()
+    deg = np.bincount(np.concatenate([src, dst])).max()
+    stats = resp.report["graph"]
+    assert stats == {"nodes": 32, "edges": len(src),
+                     "branch_edges": stats["branch_edges"],
+                     "max_degree": int(deg)}
+    assert stats["branch_edges"] > 0
+    assert resp.comm_cost == pytest.approx(
+        plan.noc.evaluate(plan.graph, p).comm_cost, rel=1e-12)
+    c = svc.stats()["counters"]
+    assert c["deploy.graphs"] == 1
+    assert (c["deploy.graph.nodes"], c["deploy.graph.edges"],
+            c["deploy.graph.branch_edges"]) == (
+        32, len(src), stats["branch_edges"])
+    assert svc.recorder.events == []

@@ -18,7 +18,7 @@ from repro.core.topology import parse_topology
 from repro.deploy import deploy_model
 from repro.kernels.delta_cost import delta_cost_pallas
 from repro.kernels.noc_segsum import link_traffic_pallas
-from repro.snn import spike_vgg16
+from repro.snn import spike_vgg16, spikformer
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +77,27 @@ def test_device_sa_scan_with_kernel_compiles(one_chip):
                               t_end_frac=1e-3, seed=0, init=None,
                               restarts=64, t0_spread=1.0, use_pallas=True,
                               refresh_every=256)
+    static["interpret"] = False          # this host is a CPU; compile Mosaic
+    shapes = [_spec(np.shape(a), a.dtype, one_chip) for a in args]
+    compiled = _sa_chains.lower(*shapes, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_device_sa_scan_on_branched_graph_compiles(one_chip):
+    """Spikformer-8-768 on a 16x16 mesh: 256 nodes, 2820 edges, incident
+    tables 56 wide, with the delta kernel (256 <= its core limit)."""
+    from repro.core.placement.device_search import _sa_chains, _sa_inputs
+
+    noc = parse_topology("mesh:16x16", link_bw=8e9, core_flops=25.6e9,
+                         hop_latency=2e-8)
+    graph = deploy_model(spikformer(), noc, method="zigzag",
+                         partition_strategy="balanced",
+                         schedule="none").graph
+    args, static = _sa_inputs(graph, noc, iters=5000, t0=0.05,
+                              t_end_frac=1e-3, seed=0, init=None,
+                              restarts=64, t0_spread=1.0, use_pallas=True,
+                              refresh_every=256)
+    assert args[4].shape == (257, 56)
     static["interpret"] = False          # this host is a CPU; compile Mosaic
     shapes = [_spec(np.shape(a), a.dtype, one_chip) for a in args]
     compiled = _sa_chains.lower(*shapes, **static).compile()
