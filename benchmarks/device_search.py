@@ -47,7 +47,7 @@ def _comm(noc, graph, placement) -> float:
 def _delta_parity(noc, graph, swaps: int = 200) -> dict:
     """Numpy O(degree) delta vs full(after) - full(before) over a random
     swap stream, plus the Pallas kernel vs the same numpy reference."""
-    from repro.kernels.delta_cost import delta_cost_pallas
+    from repro.kernels.delta_cost import delta_cost_pallas, incident_keys
     tbl = build_incident_tables(graph)
     rng = np.random.default_rng(0)
     slots = rng.permutation(noc.n_cores)
@@ -60,16 +60,18 @@ def _delta_parity(noc, graph, swaps: int = 200) -> dict:
         max_err = max(max_err, abs(d - (_comm(noc, graph, slots[:graph.n])
                                         - before)))
 
-    # Pallas gather/segment-sum kernel vs a dense-indexing float32 reference
-    C, K, R = noc.n_cores, 64, 4
+    # Pallas row-select kernel vs the same numpy reference on random swaps
+    C, R = noc.n_cores, 4
     hops = np.asarray(
         [[noc.hops(s, t) for t in range(C)] for s in range(C)],
         dtype=np.float32)
-    sb, db, sa_, da = (rng.integers(0, C, (R, K)) for _ in range(4))
-    vol = rng.integers(0, 100, (R, K)).astype(np.float32)
-    ref = (vol * (hops[sa_, da] - hops[sb, db])).sum(axis=1)
-    out = np.asarray(delta_cost_pallas(sb, db, sa_, da, vol, hops,
-                                       interpret=True))
+    slots_r = np.stack([rng.permutation(C) for _ in range(R)])
+    i_r, j_r = rng.integers(0, C, R), rng.integers(0, C, R)
+    ref = np.array([delta_comm_cost(noc, graph, slots_r[k], int(i_r[k]),
+                                    int(j_r[k]), tbl) for k in range(R)])
+    out = np.asarray(delta_cost_pallas(
+        slots_r, i_r, j_r, incident_keys(tbl.other, tbl.is_src), tbl.vol,
+        hops, hops.T, n=graph.n, interpret=True))
     pallas_err = float(np.abs(out - ref).max() / max(np.abs(ref).max(), 1.0))
     return {"numpy_max_abs_err": float(max_err),
             "numpy_exact": max_err == 0.0,

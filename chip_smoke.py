@@ -146,7 +146,8 @@ def phase_a(common):
 
 def phase_b(hier, vgg):
     from repro.core.noc_batch import build_incident_tables, delta_comm_cost
-    from repro.core.placement.device_search import (_sa_chains, _sa_inputs,
+    from repro.core.placement.device_search import (_delta_tables,
+                                                    _sa_chains, _sa_inputs,
                                                     _swap_delta)
     from repro.deploy import deploy_model
     from repro.obs import Recorder
@@ -188,10 +189,10 @@ def phase_b(hier, vgg):
     slots = np.stack([rng.permutation(S) for _ in range(DELTA_SWAPS)])
     i = rng.integers(0, S, DELTA_SWAPS)
     j = rng.integers(0, S, DELTA_SWAPS)
-    hops_f, inc_other, inc_vol, inc_src = args[7], args[4], args[5], args[6]
-    swap = jax.jit(_swap_delta, static_argnums=(7, 8, 9))
-    got = np.asarray(swap(slots.astype(np.int32), i, j, hops_f, inc_other,
-                          inc_vol, inc_src, graph.n, True, static["interpret"]))
+    tables = _delta_tables(args[7], args[4], args[5], args[6], True)
+    swap = jax.jit(_swap_delta, static_argnums=(4, 5, 6))
+    got = np.asarray(swap(slots.astype(np.int32), i, j, tables, graph.n,
+                          True, static["interpret"]))
     tbl = build_incident_tables(graph)
     ref = np.array([delta_comm_cost(hier, graph, slots[k], int(i[k]),
                                     int(j[k]), tbl)

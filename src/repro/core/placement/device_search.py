@@ -16,11 +16,12 @@ single ``jax.jit``-ed ``lax.scan`` dispatch:
   returns the best chain. Chain ``c`` draws from ``fold_in(key(seed), c)``,
   so chain 0 is bit-identical whatever ``restarts`` is (more restarts can
   only improve the returned best). The per-swap delta is evaluated either by
-  plain jax hop-matrix gathers (CPU default) or by the tiled Pallas one-hot
-  matmul kernel :func:`repro.kernels.delta_cost.delta_cost_pallas`
+  plain jax hop-matrix gathers (CPU default) or by the Pallas row-select
+  kernel :func:`repro.kernels.delta_cost.delta_cost_pallas`
   (``use_pallas=True``; interpret mode on CPU, Mosaic on TPU — the default on
   TPU hosts, where dynamic gathers lower poorly, for fabrics whose hop matrix
-  fits the kernel's VMEM block: :data:`PALLAS_DELTA_MAX_CORES`). Float32 drift of the
+  and its transpose fit the kernel's VMEM: :data:`PALLAS_DELTA_MAX_CORES`);
+  the ``sa.device`` event names the path (``delta_path``). Float32 drift of the
   accumulated cost is bounded by an exact full re-evaluation every
   ``refresh_every`` steps (``lax.cond``, still on device).
 * :func:`genetic_device` — the OX1-crossover evolutionary search as a scanned
@@ -58,17 +59,17 @@ from .baselines import core_pool, sigmate, zigzag
 import jax
 import jax.numpy as jnp
 
-from ...kernels.delta_cost import delta_cost_pallas
+from ...kernels.delta_cost import delta_cost_pallas, incident_keys
 
 
 #: the phases of one device SA search, each a span
 SA_PHASES = ("sa.prepare", "sa.run", "sa.select")
 
 #: Largest fabric (cores) whose delta kernel runs on TPU by default: the
-#: kernel holds the whole hop matrix, padded to 128-multiples, as one VMEM
-#: block, double-buffered. At 1024 cores that is 2 x 4 MiB and compiles for
-#: v5e; at 2048 cores, 2 x 16 MiB, it runs out of VMEM. Larger fabrics take
-#: the plain-jax gather path.
+#: kernel holds the hop matrix and its transpose in VMEM, one buffer each.
+#: At 1024 cores that is 2 x 4 MiB and compiles for v5e; at 1500 cores,
+#: 2 x 8.6 MiB, it runs out of VMEM. Larger fabrics take the plain-jax
+#: gather path.
 PALLAS_DELTA_MAX_CORES = 1024
 
 
@@ -100,14 +101,27 @@ def _full_cost(slots, hops_f, e_src, e_dst, e_vol, n: int):
     return jnp.sum(e_vol * hops_f[p[:, e_src], p[:, e_dst]], axis=1)
 
 
-def _swap_delta(slots, i, j, hops_f, inc_other, inc_vol, inc_src, n: int,
-                use_pallas: bool, interpret: bool):
+def _delta_tables(hops_f, inc_other, inc_vol, inc_src, use_pallas: bool):
+    """The loop-invariant operands of :func:`_swap_delta` for the chosen
+    path, built once per search outside the scan."""
+    if use_pallas:
+        return (incident_keys(inc_other, inc_src), inc_vol, hops_f, hops_f.T)
+    return (hops_f, inc_other, inc_vol, inc_src)
+
+
+def _swap_delta(slots, i, j, tables, n: int, use_pallas: bool,
+                interpret: bool):
     """O(degree) comm-cost delta of swapping ``slots[r, i[r]]``/``slots[r, j[r]]``.
 
     Device transcription of :func:`repro.core.noc_batch.delta_comm_cost`,
-    batched over the chain axis. Free-slot indices resolve to the all-padding
+    batched over the chain axis; ``tables`` is :func:`_delta_tables`' for
+    the same ``use_pallas``. Free-slot indices resolve to the all-padding
     sentinel row ``n`` of the incident tables, so no branching is needed.
     """
+    if use_pallas:
+        return delta_cost_pallas(slots, i, j, *tables, n=n,
+                                 interpret=interpret)
+    hops_f, inc_other, inc_vol, inc_src = tables
     R = slots.shape[0]
     rows = jnp.arange(R)
     ci, cj = slots[rows, i], slots[rows, j]
@@ -137,12 +151,6 @@ def _swap_delta(slots, i, j, hops_f, inc_other, inc_vol, inc_src, n: int,
     dst_b = jnp.where(is_s, oc_b, cu_before)
     src_a = jnp.where(is_s, cu_after, oc_a)
     dst_a = jnp.where(is_s, oc_a, cu_after)
-    if use_pallas:
-        D2 = 2 * oth.shape[2]
-        return delta_cost_pallas(
-            src_b.reshape(R, D2), dst_b.reshape(R, D2),
-            src_a.reshape(R, D2), dst_a.reshape(R, D2),
-            vol.reshape(R, D2), hops_f, interpret=interpret)
     C = hops_f.shape[0]
     flat = jnp.concatenate([src_a * C + dst_a, src_b * C + dst_b], axis=1)
     h = jnp.take(hops_f, flat)                      # [R, 4, D]
@@ -162,6 +170,7 @@ def _sa_chains(slots0, keys0, t0_vec, cooling, inc_other, inc_vol, inc_src,
     cost0 = _full_cost(slots0, hops_f, e_src, e_dst, e_vol, n)
     t_init = jnp.maximum(t0_vec * jnp.maximum(cost0, 1.0), 1e-9)
     rows = jnp.arange(R)
+    tables = _delta_tables(hops_f, inc_other, inc_vol, inc_src, use_pallas)
     # draw every chain's whole proposal stream up front (3 batched threefry
     # calls instead of 4 splits per step — per-step key management dominates
     # a CPU scan otherwise); chain c's stream is a function of keys0[c] only
@@ -177,8 +186,7 @@ def _sa_chains(slots0, keys0, t0_vec, cooling, inc_other, inc_vol, inc_src,
         slots, cost, best_slots, best_cost, t = carry
         it, i, j, u = xs
         degenerate = (i == j) | ((i >= n) & (j >= n))
-        delta = _swap_delta(slots, i, j, hops_f, inc_other, inc_vol, inc_src,
-                            n, use_pallas, interpret)
+        delta = _swap_delta(slots, i, j, tables, n, use_pallas, interpret)
         accept = ~degenerate & (
             (delta <= 0)
             | (u < jnp.exp(jnp.minimum(-delta / jnp.maximum(t, 1e-9), 0.0))))
@@ -314,6 +322,8 @@ def simulated_annealing_device(graph, noc, iters: int = 5000,
                        best_chain=win, best_cost=float(best_cost[win]),
                        chain_best_mean=float(best_cost.mean()),
                        use_pallas=static["use_pallas"],
+                       delta_path=("row_select" if static["use_pallas"]
+                                   else "gather"),
                        refresh_every=refresh_every)
     return placement
 

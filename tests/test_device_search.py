@@ -105,16 +105,64 @@ def test_delta_on_degraded_topology():
     assert _comm(dt, g, slots[:g.n]) != _comm(noc, g, slots[:g.n])
 
 
-def test_pallas_delta_kernel_matches_numpy():
-    from repro.kernels.delta_cost import delta_cost_pallas
-    rng = np.random.default_rng(0)
-    R, K, C = 4, 23, 32
-    hops = rng.integers(0, 9, (C, C)).astype(np.float32)
-    sb, db, sa_, da = (rng.integers(0, C, (R, K)) for _ in range(4))
-    vol = rng.integers(0, 40, (R, K)).astype(np.float32)
-    ref = (vol * (hops[sa_, da] - hops[sb, db])).sum(axis=1)
-    out = np.asarray(delta_cost_pallas(sb, db, sa_, da, vol, hops,
-                                       interpret=True))
+def _hub_graph(n, degree, seed):
+    """A chain over nodes 1..n-1 and node 0 joined to ``degree`` others, as
+    source or destination in turn: incident degree exactly ``degree``, most
+    rows padded. Volumes are eighths, so float32 sums are exact."""
+    from repro.core.graph import LogicalGraph
+    rng = np.random.default_rng(seed)
+    adj = np.zeros((n, n))
+    for u in range(1, n - 1):
+        adj[u, u + 1] = rng.integers(1, 4000) / 8
+    for k, v in enumerate(rng.choice(np.arange(1, n), degree, replace=False)):
+        adj[(0, v) if k % 2 else (v, 0)] = rng.integers(1, 4000) / 8
+    return LogicalGraph(adj, np.ones(n), np.ones(n))
+
+
+@pytest.mark.parametrize("rows,cols,n,degree,faulty", [
+    (8, 8, 40, 4, False),       # degree 4 on 64 cores, 24 free slots
+    (8, 8, 60, 56, False),      # degree 56 on 64 cores
+    (16, 16, 200, 4, False),    # degree 4 on 256 cores
+    (16, 16, 200, 56, False),   # degree 56 on 256 cores
+    (4, 8, 20, 6, True),        # degraded fabric: hops not symmetric
+])
+def test_pallas_delta_kernel_matches_numpy(rows, cols, n, degree, faulty):
+    """The kernel (interpret mode) equals the numpy reference exactly on
+    non-integer volumes, padding rows, free-slot sentinels, a-b edges and
+    degenerate swaps; 12 chains leave a tail after the kernel's groups."""
+    import jax.numpy as jnp
+
+    from repro.core.noc_batch import batched_noc
+    from repro.kernels.delta_cost import delta_cost_pallas, incident_keys
+    noc = NoC(rows, cols)
+    topo = degrade(noc, links=(5,), nodes=(9,)) if faulty else noc
+    g = _hub_graph(n, degree, seed=n + degree)
+    tbl = build_incident_tables(g)
+    assert tbl.other.shape == (n + 1, degree)
+    assert (g.adj % 1).any()
+    hops = batched_noc(topo).tables.hops
+    assert faulty == (hops != hops.T).any()
+    pool = core_pool(topo)
+    pool = np.arange(pool) if isinstance(pool, int) else np.asarray(pool)
+    rng = np.random.default_rng(degree)
+    R = 12
+    slots = np.stack([rng.permutation(pool) for _ in range(R)])
+    i = rng.integers(0, pool.size, R)
+    j = rng.integers(0, pool.size, R)
+    partner = int(tbl.other[0, 0])
+    # the hub and a partner (an a-b edge) both ways, a free slot, two free
+    # slots, the same slot twice
+    i[:5] = [0, partner, 0, n, 3]
+    j[:5] = [partner, 0, n + 1, n + 1, 3]
+    out = np.asarray(delta_cost_pallas(
+        jnp.asarray(slots, jnp.int32), jnp.asarray(i, jnp.int32),
+        jnp.asarray(j, jnp.int32),
+        incident_keys(jnp.asarray(tbl.other), jnp.asarray(tbl.is_src)),
+        jnp.asarray(tbl.vol, jnp.float32), jnp.asarray(hops, jnp.float32),
+        jnp.asarray(hops.T, jnp.float32), n=n, interpret=True))
+    ref = np.array([delta_comm_cost(topo, g, slots[r], int(i[r]), int(j[r]),
+                                    tbl) for r in range(R)])
+    assert (ref != 0).sum() >= R // 2     # not a vacuous comparison
     np.testing.assert_array_equal(out, ref.astype(np.float32))
 
 
@@ -170,14 +218,31 @@ def test_device_sa_deterministic_and_restarts_monotone():
     assert _comm(noc, g, p8) <= _comm(noc, g, p1)
 
 
-def test_device_sa_pallas_delta_matches_jax_delta():
-    noc = NoC(4, 8)
-    g = _int_graph(24, seed=2)
+@pytest.mark.parametrize("graph", ["chain", "spikformer"])
+def test_device_sa_pallas_delta_matches_jax_delta(graph):
+    """Same plan with and without the kernel, on a random DAG and on a tiny
+    Spikformer's branched graph (Q/K/V fan-out, residual operands)."""
+    if graph == "chain":
+        noc = NoC(4, 8)
+        g = _int_graph(24, seed=2)
+    else:
+        from repro.deploy import deploy_model
+        from repro.snn import spikformer
+        noc = NoC(8, 8)
+        model = spikformer(depth=2, dim=64, heads=4, mlp_ratio=4,
+                           n_classes=10, in_res=32, in_ch=3, T=4, patch=4)
+        g = deploy_model(model, noc, method="zigzag",
+                         partition_strategy="balanced",
+                         schedule="none").graph
+        assert build_incident_tables(g).max_degree > 8
     pj = simulated_annealing_device(g, noc, iters=150, seed=3,
                                     use_pallas=False)
+    rec = Recorder()
     pp = simulated_annealing_device(g, noc, iters=150, seed=3,
-                                    use_pallas=True)
+                                    use_pallas=True, recorder=rec)
     assert np.array_equal(pj, pp)
+    summary = [e["attrs"] for e in rec.events if e["name"] == "sa.device"]
+    assert summary[0]["delta_path"] == "row_select"
 
 
 def test_device_sa_recorder_identity_and_schema():
@@ -197,6 +262,8 @@ def test_device_sa_recorder_identity_and_schema():
     assert rec.counters.get("sa.accepted", 0) == n_acc
     summary = [e for e in rec.events if e["name"] == "sa.device"]
     assert len(summary) == 1 and summary[0]["attrs"]["restarts"] == 4
+    # on the CPU the delta takes the gather path
+    assert summary[0]["attrs"]["delta_path"] == "gather"
 
 
 def test_device_sa_on_degraded_topology():

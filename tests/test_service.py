@@ -458,11 +458,16 @@ def test_http_concurrent_posts_micro_batch():
     thread.start()
     url = f"http://127.0.0.1:{server.server_address[1]}"
     try:
+        # requests built up front and posted together: a request that lands
+        # after the batching window closes is a warm start from an earlier
+        # answer, not a fused row
+        reqs = [_req(seed=20 + i, budget=120) for i in range(3)]
         resps, threads = [None] * 3, []
+        start = threading.Barrier(3)
         for i in range(3):
             def run(i=i):
-                resps[i] = request_over_http(url, _req(seed=20 + i,
-                                                       budget=120))
+                start.wait()
+                resps[i] = request_over_http(url, reqs[i])
             threads.append(threading.Thread(target=run))
         for t in threads:
             t.start()

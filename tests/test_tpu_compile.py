@@ -46,15 +46,21 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("R,K,C", [
-    (64, 32, 64),       # S-VGG16 on hier:2x2:4x4, 64 restarts
-    (64, 64, 1024),     # 1024-core fabric: the delta kernel's size limit
+@pytest.mark.parametrize("R,n,D,C", [
+    (64, 64, 4, 64),        # S-ResNet50 on the 8x8 mesh, 64 restarts
+    (64, 256, 56, 256),     # Spikformer-8-768 on the 16x16 mesh
+    (64, 1024, 32, 1024),   # 1024-core fabric: the delta kernel's size limit
 ])
-def test_delta_cost_kernel_compiles(one_chip, R, K, C):
-    ent = [_spec((R, K), jnp.int32, one_chip)] * 4
-    compiled = jax.jit(delta_cost_pallas).lower(
-        *ent, _spec((R, K), jnp.float32, one_chip),
-        _spec((C, C), jnp.float32, one_chip)).compile()
+def test_delta_cost_kernel_compiles(one_chip, R, n, D, C):
+    def delta(slots, i, j, key, vol, hops, hops_t):
+        return delta_cost_pallas(slots, i, j, key, vol, hops, hops_t, n=n)
+
+    compiled = jax.jit(delta).lower(
+        _spec((R, C), jnp.int32, one_chip),
+        *[_spec((R,), jnp.int32, one_chip)] * 2,
+        _spec((n + 1, D), jnp.int32, one_chip),
+        _spec((n + 1, D), jnp.float32, one_chip),
+        *[_spec((C, C), jnp.float32, one_chip)] * 2).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
