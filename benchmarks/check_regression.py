@@ -207,8 +207,8 @@ SUITES = {
     "service": [
         # serving-layer acceptance bits: cached answers must clear the 50x
         # speedup floor over the cold p50, warm near-misses must land within
-        # the cost band at under half the cold wall, fused batch rows must
-        # be bit-identical to their solo cold searches, and a reloaded cache
+        # the cost band at under half the cold wall, batch rows must be
+        # bit-identical to their solo cold searches, and a reloaded cache
         # must still hit. Raw latency percentiles are recorded, never gated —
         # except the hit p50's generous absolute ceiling (a hit is a hash +
         # dict lookup; 50 ms of slack is orders of magnitude).
@@ -219,20 +219,19 @@ SUITES = {
         Metric("warm.status_warm", expect=True),
         Metric("warm.cost_ok", expect=True),
         Metric("warm.time_ok", expect=True),
-        Metric("fused.results_match", expect=True),
+        Metric("batch.results_match", expect=True),
         Metric("persistence.hit_after_reload", expect=True),
         # the seeded SA searches behind the service are numpy-deterministic
         Metric("cold.objective_cost", rtol=DET),
         Metric("warm.objective_cost", rtol=DET),
         Metric("warm.attempts", rtol=DET),
-        Metric("fused.costs.0", rtol=DET),
-        Metric("fused.costs.3", rtol=DET),
-        # deterministic service work counters (hits/misses/warm/fused rows)
+        Metric("batch.costs.0", rtol=DET),
+        Metric("batch.costs.3", rtol=DET),
+        # deterministic service work counters (hits/misses/warm starts)
         Metric("counters.service_requests", rtol=DET),
         Metric("counters.service_hits", rtol=DET),
         Metric("counters.service_misses", rtol=DET),
         Metric("counters.service_warm_starts", rtol=DET),
-        Metric("counters.service_fused_rows", rtol=DET),
     ],
     "fault_replace": [
         # the online re-placement loop is fully deterministic (seeded SA on

@@ -54,7 +54,7 @@ def main() -> None:
     # multilevel repeats a 200k-iteration flat SA reference and places a
     # 16k-node graph (the nightly job runs the full sweep as its own step);
     # service repeats dozens of full-budget cold deployments for the cold /
-    # warm / fused latency percentiles (nightly runs its full sweep too)
+    # warm / batch latency percentiles (nightly runs its full sweep too)
     fast_skip = {"fig8", "noc_eval", "ppo_pipeline", "deploy_e2e", "multichip",
                  "fault_replace", "device_search", "multilevel", "service"}
     print("name,us_per_call,derived")

@@ -10,9 +10,9 @@ S-ResNet18 deployment request:
   model/topology/partition, different seed) warm-starts from the cached
   placement: final cost within 5% of the full cold search on that request,
   at <= 50% of its wall time.
-* **fused batches** — k concurrent cold same-graph requests run as rows of
-  one batched-scorer dispatch and every row must match its *solo cold*
-  ``execute_request`` result bit-for-bit (batching is throughput-only).
+* **batches** — k cold same-graph requests submitted as one batch find no
+  donor in the cache, so every row must match its *solo cold*
+  ``execute_request`` result bit-for-bit.
 * **persistence** — a cache saved to JSON and reloaded in a fresh service
   still answers the original request as a hit.
 
@@ -21,7 +21,7 @@ it gates the derived booleans (``speedup_ok``, ``cost_ok``, ``time_ok``,
 ``results_match``, ``hit_after_reload``), an absolute ceiling on the hit
 p50 (a hit is a hash + dict lookup; 50 ms of slack is three orders of
 magnitude), the numpy-deterministic seeded costs at the tight band, and
-the service's deterministic hit/miss/warm/fused work counters.
+the service's deterministic hit/miss/warm work counters.
 
 Emits ``results/BENCH_service.json`` and run.py CSV rows.
 """
@@ -53,7 +53,7 @@ WARM_REPEATS = {"smoke": 5, "full": 7}
 SPEEDUP_FLOOR = 50.0          # acceptance: cached >= 50x faster than cold p50
 WARM_COST_BAND = 1.05         # acceptance: warm cost <= 105% of cold cost
 WARM_WALL_BAND = 0.5          # acceptance: warm wall <= 50% of cold wall
-FUSE_ROWS = 4
+BATCH_ROWS = 4
 NEAR_MISS_SEED = 777
 
 
@@ -159,33 +159,29 @@ def service(smoke: bool = False, json_path: str | None = None):
                      f"(band {WARM_WALL_BAND:g}) "
                      f"ok={record['warm']['cost_ok'] and record['warm']['time_ok']}"))
 
-    # ---- fused batch vs solo cold ----------------------------------------
-    fuse_reqs = [make_req(100 + i) for i in range(FUSE_ROWS)]
-    svc_f = PlacementService(recorder=recorder)
+    # ---- batch vs solo cold -----------------------------------------------
+    batch_reqs = [make_req(100 + i) for i in range(BATCH_ROWS)]
+    svc_b = PlacementService(recorder=recorder)
     t0 = time.perf_counter()
-    fused = svc_f.submit_batch(fuse_reqs)
+    batch = svc_b.submit_batch(batch_reqs)
     batch_wall = time.perf_counter() - t0
     serial_wall, match = 0.0, True
-    for req, resp in zip(fuse_reqs, fused):
+    for req, resp in zip(batch_reqs, batch):
         solo, us = timed(execute_request, req)
         serial_wall += us / 1e6
         match = match and bool(
-            resp.fused
+            resp.status == "miss"
             and np.array_equal(np.asarray(resp.placement),
                                solo.placement.placement)
             and resp.objective_cost == solo.placement.objective_cost)
-    record["fused"] = {
-        "rows": FUSE_ROWS, "results_match": match,
+    record["batch"] = {
+        "rows": BATCH_ROWS, "results_match": match,
         "batch_wall_s": batch_wall, "serial_wall_s": serial_wall,
-        "throughput_rps": FUSE_ROWS / max(batch_wall, 1e-12),
-        "serial_rps": FUSE_ROWS / max(serial_wall, 1e-12),
-        "costs": [r.objective_cost for r in fused],
+        "costs": [r.objective_cost for r in batch],
     }
-    rows_out.append(("service.fused", batch_wall / FUSE_ROWS * 1e6,
-                     f"rows={FUSE_ROWS} batch={batch_wall:.2f}s "
-                     f"serial={serial_wall:.2f}s "
-                     f"throughput={record['fused']['throughput_rps']:.1f}rps "
-                     f"bit_identical={match}"))
+    rows_out.append(("service.batch", batch_wall / BATCH_ROWS * 1e6,
+                     f"rows={BATCH_ROWS} batch={batch_wall:.2f}s "
+                     f"serial={serial_wall:.2f}s bit_identical={match}"))
 
     # ---- persistence: save -> reload -> hit -------------------------------
     with tempfile.TemporaryDirectory() as td:

@@ -86,6 +86,11 @@ METHODS = ("zigzag", "sigmate", "random_search", "simulated_annealing",
 METHOD_ALIASES = {"sa": "simulated_annealing", "ga": "genetic",
                   "rs": "random_search", "ml": "multilevel"}
 
+# total evaluations a search spends when the caller gives no budget
+DEFAULT_BUDGET = {"random_search": 2000, "simulated_annealing": 5000,
+                  "genetic": 6400, "population_random_search": 2000,
+                  "population_simulated_annealing": 16000}
+
 # arguments optimize_placement supplies itself — never forwardable via **kw
 _DRIVER_PARAMS = frozenset({"graph", "noc", "seed", "backend", "objective",
                             "recorder", "budget", "generations", "iters",
@@ -220,6 +225,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
     # RL methods have no init hook; a user-supplied ``init`` (e.g. a fast
     # device-SA placement) joins the best-of candidate set like the chip seed
     rl_init = (kw.pop("init", None) if method in ("ppo", "policy") else None)
+    evals = budget or DEFAULT_BUDGET.get(method)
     with maybe_span(recorder, f"place.{method}", seed=seed,
                     backend=bk) as sp:
         if method == "zigzag":
@@ -228,10 +234,10 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
             placement = baselines.sigmate(graph.n, noc)
         elif method == "random_search":
             placement = baselines.random_search(
-                graph, noc, iters=kw.pop("iters", None) or budget or 2000,
+                graph, noc, iters=kw.pop("iters", None) or evals,
                 seed=seed, backend=bk, objective=ob, recorder=recorder, **kw)
         elif method == "simulated_annealing":
-            iters = kw.pop("iters", None) or budget or 5000
+            iters = kw.pop("iters", None) or evals
             if bk == "device":
                 placement = device_search.simulated_annealing_device(
                     graph, noc, iters=iters, seed=seed, objective=ob,
@@ -242,13 +248,13 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
                     objective=ob, recorder=recorder, **kw)
         elif method == "population_random_search":
             placement = population.random_search_population(
-                graph, noc, iters=kw.pop("iters", None) or budget or 2000,
+                graph, noc, iters=kw.pop("iters", None) or evals,
                 seed=seed, backend=bk, objective=ob, recorder=recorder, **kw)
         elif method == "population_simulated_annealing":
             # budget counts total evaluations for every method; population SA
             # performs pop_size evaluations per lock-step iteration
             pop = max(1, kw.get("pop_size", 16))
-            iters = kw.pop("iters", None) or max(1, (budget or 16000) // pop)
+            iters = kw.pop("iters", None) or max(1, evals // pop)
             placement = population.simulated_annealing_population(
                 graph, noc, iters=iters, seed=seed, backend=bk, objective=ob,
                 recorder=recorder, **kw)
@@ -260,7 +266,7 @@ def optimize_placement(graph, noc, method: str = "ppo", seed: int = 0,
             pop = kw.setdefault("pop_size", 64)
             gens = kw.pop("generations", None)
             if gens is None:
-                gens = max(1, (budget or 6400) // max(pop, 1) - 1)
+                gens = max(1, evals // max(pop, 1) - 1)
             if bk == "device":
                 placement = device_search.genetic_device(
                     graph, noc, generations=gens, seed=seed,

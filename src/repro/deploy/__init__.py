@@ -11,9 +11,9 @@ Deployment-as-a-service lives on top: :class:`DeployRequest`
 (:mod:`repro.deploy.request`) canonicalizes one deployment call into a
 hashable, JSON-able value object; :class:`PlanCache` / :class:`PlacementService`
 (:mod:`repro.deploy.plancache` / :mod:`repro.deploy.service`) serve cached
-plans, warm-start near misses, and fuse concurrent same-topology searches
-into one batched dispatch. ``python -m repro.deploy serve`` runs the HTTP
-server; ``... request`` is the client.
+plans, warm-start near misses, and run a cold ``execute_request`` for
+anything else. ``python -m repro.deploy serve`` runs the HTTP server;
+``... request`` is the client.
 """
 from .objective import (EnergyModel, MigrationSpec, Objective,  # noqa: F401
                         OBJECTIVES, as_objective, objective_scorer,

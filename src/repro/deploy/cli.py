@@ -44,9 +44,9 @@ per-event recovery table, and before/after hotspot reports::
 
 ``repro-deploy serve`` runs the persistent placement service
 (:mod:`repro.deploy.service`): plan caching keyed by canonical
-:class:`repro.deploy.request.DeployRequest` identity, near-miss warm starts,
-fused batched dispatch for concurrent same-graph requests. ``repro-deploy
-request`` is the client. ``report``/``replay`` accept ``--plan PATH|URL`` to
+:class:`repro.deploy.request.DeployRequest` identity and near-miss warm
+starts; anything else is a cold search. ``repro-deploy request`` is the
+client. ``report``/``replay`` accept ``--plan PATH|URL`` to
 reuse a served/cached plan instead of re-deploying::
 
     PYTHONPATH=src python -m repro.deploy serve --port 8642 \\
@@ -428,9 +428,8 @@ def serve_main(argv=None) -> int:
         description="Persistent placement service: POST /deploy answers "
                     "DeployRequest JSON from the plan cache (exact hits), "
                     "warm-starts near misses from cached placements, and "
-                    "fuses concurrent same-graph cold requests into one "
-                    "batched search dispatch. GET /stats for p50/p99 request "
-                    "latencies and hit/miss/warm counters.")
+                    "runs a cold search for anything else. GET /stats for "
+                    "p50/p99 request latencies and hit/miss/warm counters.")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8642)
     ap.add_argument("--cache", default=None, metavar="PATH",
@@ -443,10 +442,7 @@ def serve_main(argv=None) -> int:
                     help="micro-batch size cap for concurrent requests")
     ap.add_argument("--window-ms", type=float, default=10.0,
                     help="micro-batching window: requests arriving within "
-                         "it share one dispatch")
-    ap.add_argument("--no-fuse", action="store_true",
-                    help="disable fused batched search (serial per-request "
-                         "searches; answers are identical by construction)")
+                         "it are answered as one batch")
     ap.add_argument("--warm-budget-frac", type=float, default=0.4,
                     help="first warm-start attempt budget as a fraction of "
                          "the request's full budget")
@@ -460,7 +456,7 @@ def serve_main(argv=None) -> int:
         print(f"# loaded {len(cache)} cached plans from {args.cache}")
     else:
         cache = PlanCache(max_entries=args.max_entries)
-    service = PlacementService(cache=cache, fuse=not args.no_fuse,
+    service = PlacementService(cache=cache,
                                warm_budget_frac=args.warm_budget_frac,
                                warm_threshold=args.warm_threshold)
     server, queue = make_server(service, host=args.host, port=args.port,
@@ -537,8 +533,7 @@ def request_main(argv=None) -> int:
     except OSError as e:
         ap.error(f"cannot reach placement service at {args.url}: {e}")
     warm = f" warm_from={resp.warm_from[:12]}" if resp.warm_from else ""
-    fused = " (fused batch row)" if resp.fused else ""
-    print(f"{resp.status}{fused}{warm}: {req.describe()}")
+    print(f"{resp.status}{warm}: {req.describe()}")
     print(f"cache_key={resp.cache_key}")
     print(f"objective_cost={resp.objective_cost:.6e} "
           f"comm_cost={resp.comm_cost:.6e} "

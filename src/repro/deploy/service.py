@@ -3,7 +3,8 @@
 Every ``deploy_model`` call used to rebuild topology tables and run a cold
 search. The paper's setting is the opposite: one long-lived near-storage
 system, many SNN models repeatedly (re)deployed onto it. This module is the
-serving layer that amortizes the search:
+serving layer that amortizes the search. It answers each request one of
+three ways:
 
 * **exact hits** — requests are canonical :class:`~repro.deploy.request.
   DeployRequest` values; a repeat of the same key (model-spec hash, topology
@@ -17,12 +18,13 @@ serving layer that amortizes the search:
   :func:`repro.deploy.runtime.run_scenario` until the warm cost is within
   ``warm_threshold`` of the donor's. The init-seeded searches keep the best
   candidate seen — warm results never regress below the donor.
-* **fused batches** — concurrent cold requests on the same topology+graph
-  (think: a seed/parameter sweep arriving together) become *rows of one
-  batched scorer* (:func:`repro.core.noc_batch.make_scorer` already scores
-  ``[B, n]`` populations in one dispatch). The fused SA/RS loop replays each
-  row's solo RNG stream in lock step, so fused results are **bit-identical**
-  to serial ones — batching is purely a throughput optimization.
+* **cold searches** — anything else runs
+  :func:`repro.deploy.engine.execute_request`, the same call as a solo
+  ``deploy_model``.
+
+A batch (one micro-batch window, or one ``/deploy_batch`` body) is answered
+row by row, each row against the cache as the batch found it: an answer
+depends on its request and that cache, never on the other rows.
 
 :class:`PlacementService` is the in-process core (usable directly in tests
 and benchmarks); :func:`make_server` wraps it in a stdlib
@@ -30,7 +32,7 @@ and benchmarks); :func:`make_server` wraps it in a stdlib
 connections through a :class:`repro.launch.serve.MicroBatchQueue` — the same
 continuous-batching idiom as the token server. Per-request latencies land in
 the service :class:`repro.obs.Recorder` as ``service.latency_s`` histograms
-(p50/p99 via ``/stats``), and hit/miss/warm/fused/compile counts, a
+(p50/p99 via ``/stats``), and hit/miss/warm/compile counts, a
 cold search's own work counts (``ppo.sample.programs``) and the logical
 graphs its plans were made on (``deploy.graphs``, ``deploy.graph.nodes``,
 ``.edges``, ``.branch_edges``) as counters; the recorder stores no spans.
@@ -43,8 +45,8 @@ its own ``latency_s``, ``queue_s``, ``report["search_phases_s"]`` and
 times of the search that made the plan).
 
 HTTP surface: ``POST /deploy`` (one request JSON -> DeployResponse JSON,
-micro-batched), ``POST /deploy_batch`` (``{"requests": [...]}`` -> fused as
-one group), ``GET /plan/<cache_key>``, ``GET /stats``, ``GET /healthz``.
+micro-batched), ``POST /deploy_batch`` (``{"requests": [...]}`` -> one
+batch), ``GET /plan/<cache_key>``, ``GET /stats``, ``GET /healthz``.
 """
 from __future__ import annotations
 
@@ -57,10 +59,9 @@ from contextlib import contextmanager
 import jax
 import numpy as np
 
-from ..core.partition import partition_model
 from ..launch.serve import MicroBatchQueue
 from ..obs import Recorder, maybe_span
-from .engine import _profiles, count_graph, execute_request
+from .engine import count_graph, execute_request
 from .plancache import PlanCache, _obj_blob
 from .request import DeployRequest
 
@@ -69,14 +70,6 @@ from .request import DeployRequest
 _WARM_METHODS = frozenset({"random_search", "simulated_annealing", "genetic",
                            "population_random_search",
                            "population_simulated_annealing"})
-
-#: optimize_placement's per-method default evaluation budgets
-_DEFAULT_BUDGET = {"random_search": 2000, "simulated_annealing": 5000,
-                   "genetic": 6400, "population_random_search": 2000,
-                   "population_simulated_annealing": 16000}
-
-#: methods the fused batch path replays bit-exactly (host backend only)
-_FUSE_METHODS = frozenset({"simulated_annealing", "random_search"})
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _compiles = [0]             # backend compiles in this process since listening
@@ -103,16 +96,15 @@ class DeployResponse:
     """One service answer: where the plan came from and what it is.
 
     ``status`` is ``"hit"`` (served from cache), ``"warm"`` (near-miss
-    warm-started from ``warm_from``'s placement) or ``"miss"`` (cold search;
-    ``fused=True`` when it ran as a row of a batched dispatch). ``latency_s``
-    is the service-side wall time of this request (for fused rows: of the
-    whole batch). ``queue_s`` is the time the request waited in the HTTP
-    micro-batch queue (0.0 for in-process calls). ``report["compiles"]``
-    counts the jax backend compiles in the process while the request ran,
-    programs loaded from the persistent compile cache included (for fused
-    rows: while the batch ran); ``report["search_phases_s"]`` holds the
-    phase times of this request's own search, summed over a warm start's
-    attempts (empty for a hit, a fused row, or a method that times none).
+    warm-started from ``warm_from``'s placement) or ``"miss"`` (cold
+    search). ``latency_s`` is the service-side wall time of this request,
+    from the start of its batch (its donor lookup included) to its answer.
+    ``queue_s`` is the time the request waited in the HTTP micro-batch queue
+    (0.0 for in-process calls). ``report["compiles"]`` counts the jax
+    backend compiles in the process while the request ran, programs loaded
+    from the persistent compile cache included; ``report["search_phases_s"]``
+    holds the phase times of this request's own search, summed over a warm
+    start's attempts (empty for a hit or a method that times none).
     ``request`` + ``placement`` are enough to
     re-materialize a live plan via
     :func:`repro.deploy.engine.instantiate_plan`.
@@ -127,7 +119,6 @@ class DeployResponse:
     latency_s: float
     warm_from: str | None = None
     attempts: int = 1
-    fused: bool = False
     queue_s: float = 0.0
 
     def to_dict(self) -> dict:
@@ -140,7 +131,7 @@ class DeployResponse:
 
 
 class PlacementService:
-    """The in-process placement service (cache + warm starts + fused batches).
+    """The in-process placement service (cache + warm starts).
 
     ``cache`` defaults to a fresh in-memory :class:`PlanCache` (load one from
     disk for restart persistence). ``recorder`` collects the service metrics
@@ -155,21 +146,18 @@ class PlacementService:
     while the cost is above ``(1 + warm_threshold) x`` the donor's (only
     comparable for same-objective donors) the budget escalates ``x
     escalation`` up to ``max_retries`` extra attempts (never beyond the full
-    budget). ``fuse=False`` disables batched dispatch (every request runs
-    serially — for A/B measurement; results are identical by construction).
+    budget).
     """
 
     def __init__(self, cache: PlanCache | None = None, recorder=None,
                  warm_budget_frac: float = 0.4, warm_threshold: float = 0.05,
-                 escalation: float = 2.0, max_retries: int = 1,
-                 fuse: bool = True):
+                 escalation: float = 2.0, max_retries: int = 1):
         self.cache = cache if cache is not None else PlanCache()
         self.recorder = recorder if recorder is not None else Recorder()
         self.warm_budget_frac = float(warm_budget_frac)
         self.warm_threshold = float(warm_threshold)
         self.escalation = float(escalation)
         self.max_retries = int(max_retries)
-        self.fuse = bool(fuse)
         self._topologies: dict = {}     # topology key tuple -> live Topology
         self._models: dict = {}         # model spec tuple -> live model
         self._lock = threading.RLock()
@@ -177,52 +165,23 @@ class PlacementService:
 
     # ---- public API --------------------------------------------------------
     def submit(self, request: DeployRequest) -> DeployResponse:
-        """Answer one request: cache hit, warm start, or cold search."""
-        with self._serving():
-            return self._submit(request)
+        """Answer one request (a batch of one): cache hit, warm start, or
+        cold search."""
+        return self.submit_batch([request])[0]
 
     def submit_batch(self, requests) -> list:
-        """Answer several concurrent requests, fusing cold same-graph
-        SA/RS groups into one batched scorer dispatch. Response order matches
-        the input. Every fused row is bit-identical to its *solo cold*
-        ``deploy_model`` result — batch composition never changes an answer.
-        (Serially submitting the same sequence can differ legitimately:
-        earlier requests' entries become warm-start donors for later ones.)
-        """
+        """Answer several requests in order, each against the cache as the
+        batch found it: warm donors are looked up before any row runs, so
+        no row warm-starts from another. A row repeating an earlier row's
+        exact key is a hit. Each answer's ``latency_s`` runs from the start
+        of the batch."""
         with self._serving(), maybe_span(None, "service.batch"):
-            requests = list(requests)
-            responses: list = [None] * len(requests)
-            groups: dict = {}
-            for idx, req in enumerate(requests):
-                key = self._fuse_key(req)
-                if key is None or req.cache_key() in self.cache:
-                    responses[idx] = self._submit(req)
-                else:
-                    groups.setdefault(key, []).append(idx)
-            for idxs in groups.values():
-                cold, seen = [], set()
-                for i in idxs:
-                    req = requests[i]
-                    ck = req.cache_key()
-                    if ck in seen:
-                        continue        # duplicate row: hits the cache below
-                    if self._warm_startable(req) and \
-                            self.cache.find_warm(req) is not None:
-                        responses[i] = self._submit(req)   # warm is cheaper
-                    else:
-                        cold.append(i)
-                        seen.add(ck)
-                if len(cold) == 1:
-                    responses[cold[0]] = self._submit(requests[cold[0]])
-                elif cold:
-                    fused = self._submit_fused([requests[i] for i in cold])
-                    for i, resp in zip(cold, fused):
-                        responses[i] = resp
-            # anything left (in-batch duplicates) is now a cache hit
-            for idx, resp in enumerate(responses):
-                if resp is None:
-                    responses[idx] = self._submit(requests[idx])
-            return responses
+            t0 = time.perf_counter()
+            rows = [(r, r.cache_key()) for r in requests]
+            donors = [None if ck in self.cache else self._donor(r)
+                      for r, ck in rows]
+            return [self._submit(r, ck, d, t0)
+                    for (r, ck), d in zip(rows, donors)]
 
     @contextmanager
     def _serving(self):
@@ -242,17 +201,15 @@ class PlacementService:
                     "latency": self.recorder.histogram_summaries()}
 
     # ---- request handling --------------------------------------------------
-    def _submit(self, request: DeployRequest) -> DeployResponse:
-        t0, c0 = time.perf_counter(), _compiles[0]
+    def _submit(self, request: DeployRequest, ck: str, donor: dict | None,
+                t0: float) -> DeployResponse:
+        c0 = _compiles[0]
         rec = self.recorder
-        ck = request.cache_key()
         rec.count("service.requests")
         entry = self.cache.get(ck)
         if entry is not None:
             rec.count("service.hits")
             return self._finish(entry, "hit", t0, c0)
-        donor = (self.cache.find_warm(request)
-                 if self._warm_startable(request) else None)
         if donor is not None:
             try:
                 with maybe_span(None, "service.deploy"):
@@ -279,7 +236,7 @@ class PlacementService:
 
     def _finish(self, entry: dict, status: str, t0: float, c0: int,
                 phases: dict | None = None, warm_from: str | None = None,
-                attempts: int = 1, fused: bool = False) -> DeployResponse:
+                attempts: int = 1) -> DeployResponse:
         """The answer to one request. Its report is the cached plan's, plus
         what this request alone did: ``compiles`` since ``c0`` and
         ``search_phases_s``, the phase times of its own search (``phases``;
@@ -295,7 +252,7 @@ class PlacementService:
             comm_cost=float(entry["comm_cost"]),
             report={**entry["report"], "compiles": _compiles[0] - c0,
                     "search_phases_s": dict(phases or {})},
-            latency_s=dt, warm_from=warm_from, attempts=attempts, fused=fused)
+            latency_s=dt, warm_from=warm_from, attempts=attempts)
 
     def _materialize(self, request: DeployRequest):
         """Live (model, topology) for a request — memoized per spec, so a
@@ -311,6 +268,12 @@ class PlacementService:
         return model, noc
 
     # ---- warm starts -------------------------------------------------------
+    def _donor(self, request: DeployRequest) -> dict | None:
+        """The cache's warm-start donor for ``request`` now, or None."""
+        if not self._warm_startable(request):
+            return None
+        return self.cache.find_warm(request)
+
     def _warm_startable(self, request: DeployRequest) -> bool:
         return (request.method in _WARM_METHODS
                 and request.copartition_iters == 0
@@ -320,12 +283,15 @@ class PlacementService:
         """(override-kwarg-name, full budget) — explicit ``iters`` wins over
         ``budget`` in the searches, so the warm fraction must scale whichever
         the request actually drives."""
+        # core.placement imports deploy, so it is imported when first needed
+        from ..core.placement.optimizer import DEFAULT_BUDGET
+
         mk = request.materialize_method_kw()
         if mk.get("iters"):
             return "iters", int(mk["iters"])
         if request.budget:
             return "budget", int(request.budget)
-        return "budget", _DEFAULT_BUDGET[request.method]
+        return "budget", DEFAULT_BUDGET[request.method]
 
     def _deploy_warm(self, request: DeployRequest, donor: dict):
         """Warm-started search: ``(best plan, attempts, phase times summed
@@ -354,165 +320,6 @@ class PlacementService:
             b = min(full, max(b + 1, int(round(b * self.escalation))))
         return best, attempts, phases
 
-    # ---- fused batches -----------------------------------------------------
-    def _fuse_key(self, request: DeployRequest):
-        """Grouping key for fusable cold requests, or None. Rows of a group
-        share everything that shapes the search (graph, objective, method,
-        budget, tuning kwargs) — only the seed may differ."""
-        if not self.fuse or request.method not in _FUSE_METHODS:
-            return None
-        if request.backend not in (None, "batch"):
-            return None
-        if request.copartition_iters != 0:
-            return None
-        return (request.warm_key(), request.method, request.backend,
-                _obj_blob(request.objective), request.budget,
-                json.dumps(request.method_kw, sort_keys=True, default=str))
-
-    def _submit_fused(self, requests) -> list:
-        t0, c0 = time.perf_counter(), _compiles[0]
-        rec = self.recorder
-        req0 = requests[0]
-        model, noc = self._materialize(req0)
-        seeds = [r.seed for r in requests]
-        with maybe_span(None, "service.fused_search"):
-            placements = _fused_cold_search(req0, model, noc, seeds)
-        rec.count("service.fused_batches")
-        rec.count("service.fused_rows", len(requests))
-        out = []
-        for req, pl in zip(requests, placements):
-            rec.count("service.requests")
-            rec.count("service.misses")
-            plan = execute_request(req, model=model, noc=noc,
-                                   _fixed_placement=pl)
-            entry = self.cache.put(req, plan)
-            count_graph(rec, entry["report"]["graph"])
-            out.append(self._finish(entry, "miss", t0, c0, fused=True))
-        return out
-
-
-# ---------------------------------------------------------------------------
-# fused cold search: lock-step bit-exact replay of the solo SA/RS loops
-# ---------------------------------------------------------------------------
-
-def _fused_cold_search(request: DeployRequest, model, noc, seeds) -> list:
-    """Placements for ``len(seeds)`` same-graph cold requests from ONE
-    batched-scorer search. Each row replays the exact solo semantics of
-    :func:`repro.core.placement.baselines.simulated_annealing` /
-    :func:`~repro.core.placement.baselines.random_search` — same per-row RNG
-    streams (acceptance draws included), same init resolution, same float64
-    scorer rows — so every returned placement is bit-identical to the serial
-    run; only the scoring dispatches are shared.
-    """
-    from ..core.noc_batch import make_scorer
-    from ..core.placement.optimizer import _chip_seed
-
-    _, profiles = _profiles(model, request.batch, request.training,
-                            request.spike_density)
-    n_usable = getattr(noc, "n_alive_cores", noc.n_cores)
-    part = partition_model(profiles, n_usable, request.partition_strategy,
-                           request.materialize_core(), topology=noc)
-    graph = part.to_graph()
-    score = make_scorer(noc, graph, request.backend or "batch",
-                        request.materialize_objective())
-    mk = request.materialize_method_kw()
-    init = mk.get("init")
-    if init is None:
-        init = _chip_seed(graph, noc)   # same seeding optimize_placement does
-    if request.method == "simulated_annealing":
-        iters = mk.get("iters") or request.budget or 5000
-        return _fused_sa(graph, noc, score, seeds, iters=int(iters),
-                         t0=mk.get("t0", 0.05),
-                         t_end_frac=mk.get("t_end_frac", 1e-3), init=init,
-                         decay_on_degenerate=mk.get("decay_on_degenerate",
-                                                    False))
-    iters = mk.get("iters") or request.budget or 2000
-    return _fused_rs(graph, noc, score, seeds, iters=int(iters), init=init)
-
-
-def _fused_sa(graph, noc, score, seeds, iters, t0, t_end_frac, init,
-              decay_on_degenerate) -> list:
-    """B independent SA chains, batch-scored: per iteration, every chain
-    draws its own proposal; the proposing rows are scored in one ``[k, n]``
-    scorer call; acceptance RNG draws happen only when a row's new cost is
-    worse (the solo loop's short-circuit). Degenerate proposals skip scoring
-    and (historically) temperature decay, exactly like the solo loop."""
-    from ..core.noc_batch import validate_placements
-    from ..core.placement.baselines import core_pool, zigzag
-
-    n = graph.n
-    base = np.array(init if init is not None else zigzag(n, noc))
-    validate_placements(noc, base, n)
-    pool = core_pool(noc)
-    cands = range(pool) if isinstance(pool, int) else pool.tolist()
-    free = [i for i in cands if i not in set(base.tolist())]
-    row = np.concatenate([base, np.asarray(free, dtype=int)])
-    B, n_slots = len(seeds), len(row)
-    slots = np.tile(row, (B, 1))
-    rngs = [np.random.default_rng(s) for s in seeds]
-    cost0 = float(score(row[None, :n])[0])
-    cost = np.full(B, cost0)
-    best = np.tile(row[:n], (B, 1))
-    best_cost = cost.copy()
-    t = np.full(B, max(t0 * max(cost0, 1.0), 1e-9))
-    cooling = t_end_frac ** (1.0 / max(iters, 1))
-    for _ in range(iters):
-        proposing, pairs = [], []
-        for b in range(B):
-            i, j = rngs[b].integers(0, n_slots, 2)
-            if i == j or (i >= n and j >= n):
-                if decay_on_degenerate:
-                    t[b] *= cooling
-                continue
-            s = slots[b]
-            s[i], s[j] = s[j], s[i]
-            proposing.append(b)
-            pairs.append((int(i), int(j)))
-        if not proposing:
-            continue
-        new_costs = score(slots[proposing][:, :n])
-        for k, b in enumerate(proposing):
-            nc = float(new_costs[k])
-            i, j = pairs[k]
-            if nc <= cost[b] or \
-                    rngs[b].random() < np.exp((cost[b] - nc) /
-                                              max(t[b], 1e-9)):
-                cost[b] = nc
-                if nc < best_cost[b]:
-                    best[b], best_cost[b] = slots[b, :n].copy(), nc
-            else:
-                s = slots[b]
-                s[i], s[j] = s[j], s[i]
-            t[b] *= cooling
-    return [best[b].copy() for b in range(B)]
-
-
-def _fused_rs(graph, noc, score, seeds, iters, init) -> list:
-    """B independent random searches, batch-scored one ``[B, n]`` call per
-    iteration; first-strict-minimum keeps, like the solo loop."""
-    from ..core.noc_batch import validate_placements
-    from ..core.placement.baselines import core_pool
-
-    n, B = graph.n, len(seeds)
-    rngs = [np.random.default_rng(s) for s in seeds]
-    best: list = [None] * B
-    best_cost = np.full(B, np.inf)
-    if init is not None:
-        init = np.asarray(init, dtype=int)
-        validate_placements(noc, init, n)
-        c0 = float(score(init[None, :])[0])
-        best = [init] * B
-        best_cost[:] = c0
-    pool = core_pool(noc)
-    for _ in range(iters):
-        props = np.stack([rngs[b].permutation(pool)[:n] for b in range(B)])
-        cs = score(props)
-        for b in range(B):
-            c = float(cs[b])
-            if c < best_cost[b]:
-                best[b], best_cost[b] = props[b].copy(), c
-    return best
-
 
 # ---------------------------------------------------------------------------
 # HTTP layer (stdlib only)
@@ -522,7 +329,7 @@ def make_server(service: PlacementService, host: str = "127.0.0.1",
                 port: int = 0, max_batch: int = 8, window_s: float = 0.01):
     """A ``ThreadingHTTPServer`` serving ``service``. ``POST /deploy``
     requests from concurrent connections funnel through one
-    :class:`MicroBatchQueue` (requests landing within ``window_s`` fuse into
+    :class:`MicroBatchQueue` (requests landing within ``window_s`` go to
     one ``submit_batch``). The queue is at ``server.queue`` — call
     ``server.queue.close()`` after ``server.shutdown()``."""
     from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer

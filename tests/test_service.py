@@ -1,4 +1,4 @@
-"""Placement-as-a-service: typed requests, plan cache, warm starts, fused
+"""Placement-as-a-service: typed requests, plan cache, warm starts,
 batches, the HTTP surface, and the method-kwarg validation that rides along.
 
 The load-bearing guarantees pinned here:
@@ -8,8 +8,9 @@ The load-bearing guarantees pinned here:
 * a `DegradedTopology` request never serves the healthy topology's cached
   plan (fault isolation of the cache key);
 * `deploy_model` delegating through the request layer is bit-identical to
-  the direct engine call, and fused batch rows are bit-identical to solo
-  cold searches;
+  the direct engine call, and a batch's rows are answered against the
+  cache as the batch found it (with no donor there, bit-identical to solo
+  cold searches);
 * typo'd method kwargs raise TypeError listing the accepted names instead
   of being silently swallowed.
 """
@@ -252,7 +253,7 @@ def test_plan_cache_hit_miss_warm_evict_save_load(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# PlacementService: hit / warm / fused
+# PlacementService: hit / warm / batch
 # ---------------------------------------------------------------------------
 
 def test_service_miss_hit_warm_flow():
@@ -273,8 +274,10 @@ def test_service_miss_hit_warm_flow():
     assert c["service.requests"] == 3
     assert c["service.hits"] == 1 and c["service.misses"] == 1
     assert c["service.warm_starts"] == 1
-    # responses survive a dict round trip (the HTTP wire format)
+    # responses survive a dict round trip (the HTTP wire format); saved
+    # answers carrying fields the response no longer has still load
     assert DeployResponse.from_dict(warm.to_dict()) == warm
+    assert DeployResponse.from_dict({**warm.to_dict(), "fused": False}) == warm
 
 
 def test_service_cross_objective_warm_start():
@@ -284,35 +287,47 @@ def test_service_cross_objective_warm_start():
     assert other.status == "warm" and other.warm_from == donor.cache_key
 
 
-def test_fused_batch_bit_identical_to_solo_cold():
-    reqs = [_req(seed=s, budget=150) for s in (11, 12, 13)]
-    svc = PlacementService(fuse=True)
+@pytest.mark.parametrize("method, seeds, budget", [
+    ("simulated_annealing", (11, 12, 13), 150),
+    ("random_search", (1, 2), 100),
+])
+def test_batch_rows_bit_identical_to_solo_cold(method, seeds, budget):
+    reqs = [_req(seed=s, budget=budget, method=method) for s in seeds]
+    svc = PlacementService()
     resps = svc.submit_batch(reqs)
-    assert all(r.status == "miss" and r.fused for r in resps)
+    assert all(r.status == "miss" for r in resps)
     for req, resp in zip(reqs, resps):
         solo = execute_request(req)
         np.testing.assert_array_equal(np.asarray(resp.placement),
                                       solo.placement.placement)
         assert resp.objective_cost == solo.placement.objective_cost
     c = svc.stats()["counters"]
-    assert c["service.fused_batches"] == 1
-    assert c["service.fused_rows"] == 3
+    assert c["service.misses"] == len(seeds)
 
 
-def test_fused_batch_dedups_and_hits_duplicates():
+def test_batch_hits_in_batch_duplicates():
     r = _req(seed=4, budget=100)
-    svc = PlacementService(fuse=True)
+    svc = PlacementService()
     a, b = svc.submit_batch([r, r])
     assert a.placement == b.placement
     assert {a.status, b.status} == {"miss", "hit"}
 
 
-def test_random_search_fuses_too():
-    reqs = [_req(seed=s, budget=100, method="random_search")
-            for s in (1, 2)]
-    resps = PlacementService(fuse=True).submit_batch(reqs)
-    for req, resp in zip(reqs, resps):
-        assert resp.fused
+def test_batch_rows_warm_only_from_donors_before_the_batch():
+    """Rows sharing a warm key each warm-start from the donor the cache held
+    when the batch arrived, never from one another; with no donor both are
+    cold, and equal their solo searches."""
+    a, b = _req(seed=31, budget=150), _req(seed=32, budget=150)
+    svc = PlacementService()
+    donor = svc.submit(_req(seed=30, budget=150))
+    ra, rb = svc.submit_batch([a, b])
+    assert (ra.status, rb.status) == ("warm", "warm")
+    assert ra.warm_from == rb.warm_from == donor.cache_key
+    assert max(ra.objective_cost, rb.objective_cost) <= donor.objective_cost
+
+    ra, rb = PlacementService().submit_batch([a, b])
+    assert (ra.status, rb.status) == ("miss", "miss")
+    for req, resp in ((a, ra), (b, rb)):
         solo = execute_request(req)
         np.testing.assert_array_equal(np.asarray(resp.placement),
                                       solo.placement.placement)
@@ -452,15 +467,17 @@ def test_http_server_roundtrip():
 
 
 def test_http_concurrent_posts_micro_batch():
-    svc = PlacementService(fuse=True)
+    """Three concurrent POSTs all get answers through the queue. Where the
+    batching windows fall decides which rows are cold and which warm-start
+    from an answer made in an earlier batch, so each status is held to its
+    own rule: a miss equals its solo cold search, a warm answer names an
+    earlier answer as its donor and costs no more than it."""
+    svc = PlacementService()
     server, queue = make_server(svc, port=0, window_s=0.1, max_batch=8)
     thread = threading.Thread(target=server.serve_forever, daemon=True)
     thread.start()
     url = f"http://127.0.0.1:{server.server_address[1]}"
     try:
-        # requests built up front and posted together: a request that lands
-        # after the batching window closes is a warm start from an earlier
-        # answer, not a fused row
         reqs = [_req(seed=20 + i, budget=120) for i in range(3)]
         resps, threads = [None] * 3, []
         start = threading.Barrier(3)
@@ -472,13 +489,22 @@ def test_http_concurrent_posts_micro_batch():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=300)
+            assert not t.is_alive()
         assert all(r is not None for r in resps)
-        # every row is still bit-identical to its solo cold search
-        for i, resp in enumerate(resps):
-            solo = execute_request(_req(seed=20 + i, budget=120))
-            np.testing.assert_array_equal(np.asarray(resp.placement),
-                                          solo.placement.placement)
+        by_key = {r.cache_key: r for r in resps}
+        for req, resp in zip(reqs, resps):
+            assert resp.status in ("miss", "warm")
+            if resp.status == "miss":
+                solo = execute_request(req)
+                np.testing.assert_array_equal(np.asarray(resp.placement),
+                                              solo.placement.placement)
+            else:
+                assert resp.warm_from in by_key
+                assert resp.warm_from != resp.cache_key
+                assert (resp.objective_cost
+                        <= by_key[resp.warm_from].objective_cost)
+        assert svc.stats()["counters"]["service.requests"] == 3
     finally:
         server.shutdown()
         server.server_close()
@@ -509,13 +535,13 @@ def test_service_recorder_stays_bounded():
     list does not grow with the requests it serves."""
     svc = PlacementService()
     svc.submit_batch([_req(seed=s, budget=60, method="random_search")
-                      for s in (5, 6)])                   # fused
+                      for s in (5, 6)])                   # two misses
     svc.submit(_req(seed=0, budget=60))                   # warm
     svc.submit(_req(seed=0, budget=60))                   # hit
     svc.submit(_req(seed=1, budget=60))                   # warm
     assert svc.recorder.events == []
     c = svc.stats()["counters"]
-    assert c["service.requests"] == 5 and c["service.fused_batches"] == 1
+    assert c["service.requests"] == 5 and c["service.misses"] == 2
     assert c["service.hits"] == 1 and c["service.warm_starts"] == 2
 
 
